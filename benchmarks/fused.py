@@ -156,8 +156,6 @@ def _chunk_cost(fn, r: int, s: int, K: int) -> dict:
     key = jax.random.PRNGKey(0)
     compiled = jax.jit(fn).lower(state, Ws, nv, key).compile()
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):  # jax < 0.6 wraps in a list
-        ca = ca[0] if ca else {}
     return {
         "flops": float(ca.get("flops", 0.0)),
         "bytes_accessed": float(ca.get("bytes accessed", 0.0)),

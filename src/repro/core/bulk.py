@@ -18,8 +18,8 @@ single multisearch over ``R.key_desc``, the Q2 decode is one multisearch over
 ``R.key_rank``, and the closing-edge check is one multisearch over ``R.ekey`` —
 three multisearch passes per batch (down from six-plus independent
 searchsorted calls), matching Theorem 4.1's O(sort(r) + sort(s)) memory-access
-accounting. ``repro.primitives.search.multisearch_bounds`` routes each pass to
-the Pallas counting kernel on TPU.
+accounting. ``repro.primitives.search.multisearch_bounds`` answers each pass
+(``jnp.searchsorted`` by default, the Pallas counting kernel by name).
 
 ``bulk_update_chunk`` scans K stacked batches inside one jit dispatch; because
 randomness is counter-based (jax.random.fold_in of the stream key with the
@@ -421,11 +421,12 @@ def bulk_update_chunk(
     resuming a stream at any batch cursor reuses the compiled program.
 
     The implementation dispatches on ``repro.primitives.ingest`` at trace
-    time: "scan" runs the reference per-batch scan; "xla" (the off-TPU
-    default) runs the fused pipeline with hoisted randomness/structures and
-    lt-trimmed searches; "pallas" additionally hands the batch loop to the
-    resident fused-ingest kernel. All three are bit-identical — the backend
-    knob trades dispatch/memory traffic, never results. Every execution
+    time: "scan" runs the reference per-batch scan; "xla" (what "auto"
+    selects on every platform) runs the fused pipeline with hoisted
+    randomness/structures and lt-trimmed searches; "pallas" additionally
+    hands the batch loop to the resident fused-ingest kernel. All three are
+    bit-identical — the backend knob trades dispatch/memory traffic, never
+    results. Every execution
     plan that chunks (``single`` and the banked plans) inherits the fused
     path through ``scheme.chunk_update`` with no signature change.
     """

@@ -62,12 +62,11 @@ assert zero overflow at the sizes exercised.
 """
 from __future__ import annotations
 
-import inspect
 from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 
 from repro.core.schemes import (
     GLOBAL,
@@ -85,25 +84,25 @@ from repro.primitives.sort import pack2, sort_by_key
 INF64 = jnp.int64(0x7FFFFFFFFFFFFFFF)
 _HASH_MULT = jnp.uint32(2654435761)
 
-if hasattr(jax, "shard_map"):
-    _sm_impl = jax.shard_map
-else:  # pragma: no cover - old jax only exports the experimental spelling
-    from jax.experimental.shard_map import shard_map as _sm_impl
-
-# the top-level export and the check_rep->check_vma rename landed in
-# different jax releases, so key the kwarg on the actual signature
-_sm_check_kw = (
-    "check_vma"
-    if "check_vma" in inspect.signature(_sm_impl).parameters
-    else "check_rep"
-)
-
 
 def _shard_map(f, mesh, *, in_specs, out_specs):
-    return _sm_impl(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        **{_sm_check_kw: False},
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
     )
+
+
+def auto_axes(mesh):
+    """``mesh`` with every axis typed ``Auto`` (None passes through).
+
+    Every plan here places arrays through ``NamedSharding`` and
+    ``with_sharding_constraint``, which only accept ``Auto`` axes, while
+    ``jax.make_mesh`` types its axes ``Explicit`` unless told otherwise. The
+    engines pass the mesh they are given through this, so a mesh from plain
+    ``jax.make_mesh`` runs the same programs as one from
+    ``repro.launch.mesh.make_stream_mesh``."""
+    if mesh is None or all(t == AxisType.Auto for t in mesh.axis_types):
+        return mesh
+    return mesh.update(axis_types=(AxisType.Auto,) * len(mesh.axis_names))
 
 
 # --------------------------------------------------------------------------
@@ -241,8 +240,9 @@ def make_banked_pjit_update(
     before the batch-structure build. Keeping the structure build replicated
     per group is deliberate: XLA's partitioner (observed on 0.4.x CPU)
     miscompiles iota-into-sharded-concat fusions when the tenant dim and the
-    batch dim shard simultaneously — and every device in a tenant group needs
-    the full batch structure for its estimator shard's multisearches anyway.
+    batch dim shard simultaneously (not reproduced on jax 0.9; ROADMAP C8)
+    — and every device in a tenant group needs the full batch structure for
+    its estimator shard's multisearches anyway.
     The estimator-dim work (reservoir draws, Q1/Q2/Q3 query vectors) stays
     sharded in both modes. ``make_banked_pjit_chunk_update`` is the K-batch
     fused variant (``scheme.chunk_update`` under the same shardings).

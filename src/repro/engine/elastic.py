@@ -54,6 +54,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.distributed import auto_axes
 from repro.engine.backends import BackendPlan, select_backend
 from repro.engine.engine import EngineConfig, SnapshotMismatch, _snapshot_config
 from repro.engine.faults import FaultInjected, check_fault
@@ -147,7 +148,7 @@ class ElasticBankEngine:
         self.batch_size = int(batch_size)
         self.groups = int(groups)
         self.chunk_size = int(chunk_size)
-        self.mesh = mesh
+        self.mesh = mesh = auto_axes(mesh)
         self._scheme_name = scheme
         self._scheme_params = scheme_params
         self._tenant_axis = tenant_axis
@@ -293,7 +294,14 @@ class ElasticBankEngine:
         read just read, and the warm key set re-sets an existing key."""
         C, s, K = self.capacity, self.batch_size, self.chunk_size
         t = self._tier
-        keys = t["fold"](self._root_keys, jnp.asarray(self._steps))
+        # key_set first: its output sharding is part of the type the chunk
+        # program is compiled for, so warming that program on keys placed
+        # any other way would leave the first real chunk to compile again.
+        # The warmup round-trip pre-compiles key_set with a host-fed
+        # operand, once per capacity tier  # repro-lint: ignore[RL303]
+        k0 = jnp.asarray(np.asarray(self._root_keys)[0:1])
+        self._root_keys = t["key_set"](self._root_keys, np.int32(0), k0)
+        keys = t["fold"](self._root_keys, self._cursors())
         zW = self._put_batch(np.zeros((C, s, 2), np.int32))
         self._state = t["update"](
             self._state, zW, jnp.zeros((C,), jnp.int32), keys
@@ -305,18 +313,22 @@ class ElasticBankEngine:
                 zWk,
                 jnp.zeros((C, K), jnp.int32),
                 self._root_keys,
-                jnp.asarray(self._steps),
+                self._cursors(),
             )
         if t["estimate_device"] is not None:
             jax.block_until_ready(t["estimate_device"](self._state))
         jax.block_until_ready(t["estimate"](self._gathered_state()))
         one = t["slot_read"](self._state, np.int32(0))
         self._state = t["slot_write"](self._state, np.int32(0), one)
-        # warmup round-trip pre-compiles key_set with a host-fed operand,
-        # once per capacity tier  # repro-lint: ignore[RL303]
-        k0 = jnp.asarray(np.asarray(self._root_keys)[0:1])
-        self._root_keys = t["key_set"](self._root_keys, np.int32(0), k0)
         jax.block_until_ready(self._state)
+
+    def _cursors(self):
+        """The per-slot step cursors as a device array. The host array is
+        copied first: on the CPU backend ``jnp.asarray`` may alias an
+        aligned numpy buffer without copying, and the dispatch that reads
+        it is asynchronous, so bumping ``_steps`` right after a dispatch
+        could otherwise change the RNG cursors that dispatch sees."""
+        return jnp.asarray(self._steps.copy())
 
     def _place_bank(self, bank):
         plan = self._tier["plan"]
@@ -424,8 +436,9 @@ class ElasticBankEngine:
         # is NOT safe here — XLA's SPMD partitioner (observed on 0.4.x CPU)
         # miscompiles concat under a sharded input mesh, double-counting the
         # replicated fields (same bug family as the iota-into-sharded-concat
-        # note in repro.core.distributed). Growing is rare (amortized by the
-        # doubling), so the one host round-trip is the robust trade.
+        # note in repro.core.distributed; neither reproduces on jax 0.9 —
+        # ROADMAP C8). Growing is rare (amortized by the doubling), so the
+        # one host round-trip is the robust trade.
         new_cap = self.capacity * 2
         pad = new_cap // 2
         host = jax.tree.map(np.asarray, self._state)
@@ -500,7 +513,7 @@ class ElasticBankEngine:
             Wb[slot], nv[slot] = self._pad(W, n)
             touched.append(slot)
             edges += int(nv[slot])
-        keys = self._tier["fold"](self._root_keys, jnp.asarray(self._steps))
+        keys = self._tier["fold"](self._root_keys, self._cursors())
         self._state = self._tier["update"](
             self._state, self._put_batch(Wb), jnp.asarray(nv), keys
         )
@@ -548,7 +561,7 @@ class ElasticBankEngine:
             self._put_chunk(Wb),
             jnp.asarray(nv),
             self._root_keys,
-            jnp.asarray(self._steps),
+            self._cursors(),
         )
         total = 0
         for slot, j in advance.items():

@@ -106,6 +106,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.distributed import auto_axes
 from repro.core.estimate import effective_groups
 from repro.core.schemes import EstimatorScheme, resolve_scheme
 from repro.engine.backends import BackendPlan, select_backend
@@ -254,7 +255,7 @@ class TriangleCountEngine:
         if config.chunk_size <= 0:
             raise ValueError(f"chunk_size must be >= 1, got {config.chunk_size}")
         self.config = config
-        self.mesh = mesh
+        self.mesh = mesh = auto_axes(mesh)
         self.scheme: EstimatorScheme = config.resolved_scheme()
         self.plan: BackendPlan = select_backend(config, mesh)
         self._update = self.plan.build(config, mesh)

@@ -25,7 +25,24 @@ from jax.experimental import pallas as pl
 Array = jax.Array
 
 
-def _count_kernel(k_ref, q_ref, lt_ref, le_ref):
+LANE = 128  # keys stream past the queries one (1, LANE) row at a time
+
+
+def _split_keys(keys: Array) -> tuple[Array, Array]:
+    """int64 keys -> (hi, lo) int32 halves whose lexicographic signed order
+    is the int64 order: ``hi`` is the arithmetic top half, ``lo`` the bottom
+    half with its sign bit flipped (unsigned order as signed). Mosaic holds
+    no 64-bit vectors, so the kernel compares the halves."""
+    k = keys.astype(jnp.int64)
+    hi = (k >> 32).astype(jnp.int32)
+    lo = jax.lax.bitcast_convert_type(
+        (k & 0xFFFFFFFF).astype(jnp.uint32) ^ jnp.uint32(0x80000000),
+        jnp.int32,
+    )
+    return hi, lo
+
+
+def _count_kernel(khi_ref, klo_ref, qhi_ref, qlo_ref, lt_ref, le_ref):
     j = pl.program_id(1)
 
     @pl.when(j == 0)
@@ -33,12 +50,23 @@ def _count_kernel(k_ref, q_ref, lt_ref, le_ref):
         lt_ref[...] = jnp.zeros_like(lt_ref)
         le_ref[...] = jnp.zeros_like(le_ref)
 
-    keys = k_ref[...]  # (C,)
-    qs = q_ref[...]  # (Q,)
-    cmp_lt = keys[None, :] < qs[:, None]  # (Q, C)
-    cmp_le = keys[None, :] <= qs[:, None]
-    lt_ref[...] += jnp.sum(cmp_lt, axis=1).astype(jnp.int32)
-    le_ref[...] += jnp.sum(cmp_le, axis=1).astype(jnp.int32)
+    qhi = qhi_ref[...]  # (Q, 1)
+    qlo = qlo_ref[...]
+
+    def row(i, acc):
+        lt, le = acc
+        khi = khi_ref[pl.ds(i, 1), :]  # (1, lane)
+        klo = klo_ref[pl.ds(i, 1), :]
+        below = khi < qhi  # (Q, lane)
+        tie = khi == qhi
+        lt = lt + (below | (tie & (klo < qlo))).astype(jnp.int32)
+        le = le + (below | (tie & (klo <= qlo))).astype(jnp.int32)
+        return lt, le
+
+    zero = jnp.zeros((qhi.shape[0], khi_ref.shape[1]), jnp.int32)
+    lt, le = jax.lax.fori_loop(0, khi_ref.shape[0], row, (zero, zero))
+    lt_ref[...] += jnp.sum(lt, axis=1, keepdims=True, dtype=jnp.int32)
+    le_ref[...] += jnp.sum(le, axis=1, keepdims=True, dtype=jnp.int32)
 
 
 @functools.partial(
@@ -54,6 +82,12 @@ def multisearch_counts(
 ) -> tuple[Array, Array]:
     """Return (count_lt, count_le) per query — the searchsorted left/right
     insertion points into ``sorted_keys`` (which must be sorted ascending).
+
+    Keys and queries are integers of up to 64 bits; both are split into
+    int32 halves (``_split_keys``) before the kernel. Keys are laid out as
+    ``(n / LANE, LANE)`` rows and queries as a ``(q, 1)`` column, so each
+    grid cell compares a ``(q_block, 1)`` query tile with ``k_block /
+    LANE`` key rows, one ``(q_block, LANE)`` compare per row.
 
     Padding: keys are padded with +INF (count as never-less), queries padded
     with anything (results for the pad tail are discarded). A query equal to
@@ -75,28 +109,35 @@ def multisearch_counts(
     maxval = jnp.array(jnp.iinfo(sorted_keys.dtype).max, sorted_keys.dtype)
     n_pad = pl.cdiv(n, k_block) * k_block
     q_pad = pl.cdiv(q, q_block) * q_block
-    keys = jnp.pad(sorted_keys, (0, n_pad - n), constant_values=maxval)
-    qs = jnp.pad(queries, (0, q_pad - q))
+    khi, klo = _split_keys(
+        jnp.pad(sorted_keys, (0, n_pad - n), constant_values=maxval)
+    )
+    qhi, qlo = _split_keys(jnp.pad(queries, (0, q_pad - q)))
+    # a key block narrower than a lane row (small interpret-mode tests)
+    # is one row of its own width
+    lane = min(LANE, k_block)
+    if k_block % lane:
+        raise ValueError(f"k_block={k_block} is not a multiple of {LANE}")
 
-    grid = (q_pad // q_block, n_pad // k_block)
+    # block indices stay int32: a literal 0 is an int64 index under x64,
+    # which Mosaic cannot lower
+    key_spec = pl.BlockSpec((k_block // lane, lane), lambda i, j: (j, j * 0))
+    q_spec = pl.BlockSpec((q_block, 1), lambda i, j: (i, i * 0))
     lt, le = pl.pallas_call(
         _count_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((k_block,), lambda i, j: (j,)),
-            pl.BlockSpec((q_block,), lambda i, j: (i,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((q_block,), lambda i, j: (i,)),
-            pl.BlockSpec((q_block,), lambda i, j: (i,)),
-        ],
+        grid=(q_pad // q_block, n_pad // k_block),
+        in_specs=[key_spec, key_spec, q_spec, q_spec],
+        out_specs=[q_spec, q_spec],
         out_shape=[
-            jax.ShapeDtypeStruct((q_pad,), jnp.int32),
-            jax.ShapeDtypeStruct((q_pad,), jnp.int32),
+            jax.ShapeDtypeStruct((q_pad, 1), jnp.int32),
+            jax.ShapeDtypeStruct((q_pad, 1), jnp.int32),
         ],
         interpret=interpret,
-    )(keys, qs)
-    return lt[:q], jnp.minimum(le[:q], n)
+    )(
+        khi.reshape(-1, lane), klo.reshape(-1, lane),
+        qhi.reshape(-1, 1), qlo.reshape(-1, 1),
+    )
+    return lt[:q, 0], jnp.minimum(le[:q, 0], n)
 
 
 def exact_multisearch_kernel(sorted_keys, queries, **kw):

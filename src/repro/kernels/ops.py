@@ -1,9 +1,10 @@
 """jit'd public wrappers around the Pallas kernels.
 
-On TPU the kernels run compiled (interpret=False); everywhere else (this CPU
-container, unit tests) they run in interpret mode, which executes the same
-kernel body in Python — the BlockSpec tiling, grid sequencing, and SMEM carry
-logic are exercised identically.
+On TPU the kernels run compiled (interpret=False), so a kernel the TPU
+compiler refuses raises there (tests/test_tpu_compile.py records which do);
+everywhere else (CPU hosts, unit tests) they run in interpret mode, which
+executes the same kernel body in Python — the BlockSpec tiling, grid
+sequencing, and SMEM carry logic are exercised identically.
 """
 from __future__ import annotations
 
@@ -30,9 +31,9 @@ def multisearch_counts_op(
 ) -> tuple[Array, Array]:
     """(count_lt, count_le) insertion points (kernel-backed).
 
-    This is the TPU target of ``repro.primitives.search.multisearch_bounds``
+    The "pallas" target of ``repro.primitives.search.multisearch_bounds``
     — the fused per-structure lookups on the bulk-update hot path land here
-    when the backend resolves to "pallas"."""
+    when that backend is forced."""
     return multisearch.multisearch_counts(
         sorted_keys,
         queries,

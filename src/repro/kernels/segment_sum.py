@@ -67,11 +67,13 @@ def segment_sum_kernel(
     out = pl.pallas_call(
         functools.partial(_segsum_kernel, out_block=out_block),
         grid=grid,
+        # block indices stay int32: a literal 0 is an int64 index under
+        # x64, which Mosaic cannot lower
         in_specs=[
             pl.BlockSpec((v_block,), lambda i, j: (j,)),
-            pl.BlockSpec((v_block, d), lambda i, j: (j, 0)),
+            pl.BlockSpec((v_block, d), lambda i, j: (j, j * 0)),
         ],
-        out_specs=pl.BlockSpec((out_block, d), lambda i, j: (i, 0)),
+        out_specs=pl.BlockSpec((out_block, d), lambda i, j: (i, i * 0)),
         out_shape=jax.ShapeDtypeStruct((m_pad, d), values.dtype),
         interpret=interpret,
     )(ids, v)

@@ -1,14 +1,37 @@
-"""Environment hooks that must run before jax initializes a backend.
+"""Environment hooks that must run before jax initializes a backend or
+compiles a program.
 
 Importing this module (like anything under ``repro``) imports jax, which is
 safe: XLA reads XLA_FLAGS when the *backend* initializes — at the first
-device query — not at import time. Callers just have to apply the hook
-before building a mesh or touching devices; the stream CLIs run it at
-module import, ahead of everything else.
+device query — not at import time, and the compilation cache is consulted at
+the first compile. Callers just have to apply the hooks before building a
+mesh or touching devices; the stream CLIs run them at module import, ahead of
+everything else.
 """
 from __future__ import annotations
 
 import os
+import pathlib
+
+import jax
+
+# the persistent compilation cache's home when the environment names none:
+# fixed and inside the checkout (git-ignored), because the directory is part
+# of what a later run must find again
+COMPILE_CACHE_DIR = pathlib.Path(__file__).resolve().parents[3] / ".jax_compile_cache"
+
+
+def use_compile_cache() -> None:
+    """Turn on JAX's persistent compilation cache.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, wins: jax reads it itself and
+    nothing is set here. Otherwise the cache goes to ``COMPILE_CACHE_DIR``.
+    A cache hit skips the backend compile, so it also skips the
+    ``backend_compile`` event that ``repro.engine.XlaCompileCounter``
+    counts."""
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        return
+    jax.config.update("jax_compilation_cache_dir", str(COMPILE_CACHE_DIR))
 
 
 def apply_host_devices(argv) -> None:

@@ -34,14 +34,6 @@ from repro.roofline.flops import cell_analytic_flops  # noqa: E402
 from repro.roofline.hlo import collective_stats  # noqa: E402
 
 
-def _ambient_mesh(mesh):
-    """Context manager making ``mesh`` ambient for with_sharding_constraint(P).
-
-    jax >= 0.7 spells it jax.set_mesh; before that, Mesh is itself the
-    context manager."""
-    return jax.set_mesh(mesh) if hasattr(jax, "set_mesh") else mesh
-
-
 def _shard(mesh, spec_tree, args_tree):
     is_p = lambda x: isinstance(x, P)
     return jax.tree.map(
@@ -49,17 +41,9 @@ def _shard(mesh, spec_tree, args_tree):
     )
 
 
-def _cost_analysis(compiled) -> dict:
-    """compiled.cost_analysis() as a dict (jax < 0.6 wraps it in a list)."""
-    ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca
-
-
 def _analyze(compiled, chips, model_flops, seconds):
     mem = compiled.memory_analysis()
-    ca = _cost_analysis(compiled)
+    ca = compiled.cost_analysis() or {}
     txt = compiled.as_text()
     coll = collective_stats(txt)
     return {
@@ -88,7 +72,7 @@ def run_model_cell(arch: str, shape: str, multi_pod: bool, overrides=None) -> di
     out_sh = None if cell.out_specs is None else _shard(mesh, cell.out_specs, None)
     t0 = time.time()
     jf = jax.jit(cell.fn, in_shardings=in_sh, out_shardings=out_sh)
-    with _ambient_mesh(mesh):  # ambient mesh for with_sharding_constraint(P)
+    with jax.set_mesh(mesh):  # ambient mesh for with_sharding_constraint(P)
         lowered = jf.lower(*cell.args)
     compiled = lowered.compile()
     rec = _analyze(compiled, mesh.size, cell.model_flops, time.time() - t0)
@@ -96,7 +80,7 @@ def run_model_cell(arch: str, shape: str, multi_pod: bool, overrides=None) -> di
     rec["cost"]["flops_analytic_total"] = fa  # None -> trust HLO flops
     rec |= {"arch": arch, "shape": shape, "mesh": "multipod" if multi_pod else "pod"}
     print(compiled.memory_analysis())
-    print({k: v for k, v in _cost_analysis(compiled).items()
+    print({k: v for k, v in (compiled.cost_analysis() or {}).items()
            if k in ("flops", "bytes accessed")})
     return rec
 

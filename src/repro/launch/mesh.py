@@ -8,17 +8,13 @@ superlinks).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
-try:  # AxisType landed in jax 0.5; older jaxlibs default every axis to Auto
-    from jax.sharding import AxisType
 
-    def _axis_kw(n: int) -> dict:
-        return {"axis_types": (AxisType.Auto,) * n}
-
-except ImportError:  # pragma: no cover - exercised on jax < 0.5
-
-    def _axis_kw(n: int) -> dict:
-        return {}
+def _axis_kw(n: int) -> dict:
+    # the engines' programs constrain shardings by name, which needs Auto
+    # axes; jax.make_mesh defaults to Explicit
+    return {"axis_types": (AxisType.Auto,) * n}
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -20,12 +20,13 @@ from __future__ import annotations
 import argparse
 import sys
 
-from repro.launch._env import apply_host_devices
+from repro.launch._env import apply_host_devices, use_compile_cache
 
 if __name__ == "__main__":
     # must run before any jax device query (see repro.launch._env); guarded
     # so merely importing this module never mutates the environment
     apply_host_devices(sys.argv)
+    use_compile_cache()
 
 import repro  # noqa: F401,E402
 from repro.core.sequential import count_triangles, local_triangle_counts

@@ -31,11 +31,12 @@ import queue
 import sys
 import threading
 
-from repro.launch._env import apply_host_devices
+from repro.launch._env import apply_host_devices, use_compile_cache
 
 if __name__ == "__main__":
     # must run before any jax device query (see repro.launch._env)
     apply_host_devices(sys.argv)
+    use_compile_cache()
 
 import numpy as np
 

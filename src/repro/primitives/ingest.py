@@ -12,14 +12,16 @@ same style as ``repro.primitives.search``:
             are hoisted out of the scan (the counter-based RNG makes every
             draw a pure function of (stream key, batch index, batch sizes)),
             and the in-scan searches run lt-trimmed ``scan_unrolled``
-            multisearches. The default off-TPU.
+            multisearches. What "auto" selects, on every platform.
   "pallas"  the resident kernel (``repro.kernels.fused_ingest``): one
             pallas_call walks all K batches over each reservoir tile, so the
             estimator state is read and written once per *chunk* instead of
             ~once per pipeline stage per batch. Structures are built by the
-            ``kernels/bitonic.py`` + ``kernels/segscan.py`` path. Interpret
-            mode off-TPU (slow; parity testing only).
-  "auto"    "pallas" on TPU, "xla" elsewhere.
+            ``kernels/bitonic.py`` + ``kernels/segscan.py`` path. Selectable
+            by name only: the TPU compiler refuses these kernels at the
+            paper's shapes (tests/test_tpu_compile.py). Compiled on TPU,
+            interpret mode elsewhere (slow; parity testing only).
+  "auto"    "xla" on every platform.
 
 The choice is resolved at trace time, so switching clears the jit caches —
 otherwise already-compiled engine programs would keep their old pipeline
@@ -67,9 +69,7 @@ def set_ingest_backend(name: str) -> None:
 def ingest_backend() -> str:
     """The pipeline ``bulk_update_chunk`` resolves to right now
     ("scan", "xla", or "pallas")."""
-    if _backend != "auto":
-        return _backend
-    return "pallas" if jax.default_backend() == "tpu" else "xla"
+    return "xla" if _backend == "auto" else _backend
 
 
 def split_randint_key(key: Array) -> tuple[Array, Array]:

@@ -2,17 +2,18 @@
 
 The paper's cache-oblivious merge-based multisearch answers m lookups against a
 sorted sequence of n key-value pairs in O(sort(n)+sort(m)) misses. With both
-sides presorted it degrades to O(scan(n+m)). On TPU we express each lookup set
-as a vectorized binary search (``jnp.searchsorted``) over presorted int64 keys;
-the Pallas kernel in repro.kernels.multisearch provides the VMEM-chunked,
-gather-free variant used on hardware.
+sides presorted it degrades to O(scan(n+m)). We express each lookup set as a
+vectorized binary search (``jnp.searchsorted``) over presorted int64 keys;
+the Pallas kernel in repro.kernels.multisearch is a gather-free counting
+variant, selectable by name.
 
 ``multisearch_bounds`` is the hot-path entry point: one call answers both
-insertion points (left/right) for a whole fused query vector, and a backend
-switch routes it to the Pallas counting kernel on TPU (gather-free, one
-streaming pass over the keys per query tile) or to ``jnp.searchsorted``
-elsewhere. Callers that fuse their lookups into one query vector per sorted
-structure pay one multisearch per structure instead of one per query role.
+insertion points (left/right) for a whole fused query vector. "auto" resolves
+to ``jnp.searchsorted`` on every platform: the counting kernel compares every
+query with every key (O(q*n) work), so whether it should ever be the default
+is for a chip benchmark to decide. Callers that fuse their lookups into one
+query vector per sorted structure pay one multisearch per structure instead
+of one per query role.
 """
 from __future__ import annotations
 
@@ -37,9 +38,9 @@ if _backend not in MULTISEARCH_BACKENDS:
 
 
 def set_multisearch_backend(name: str) -> None:
-    """Force the multisearch backend: "auto" (Pallas on TPU, XLA elsewhere),
-    "xla" (jnp.searchsorted), or "pallas" (counting kernel; interpret mode off
-    TPU — slow, for parity testing only). The choice is resolved at trace
+    """Force the multisearch backend: "auto" (= "xla" on every platform),
+    "xla" (jnp.searchsorted), or "pallas" (counting kernel; compiled on TPU,
+    interpret mode elsewhere — slow, for parity testing only). The choice is resolved at trace
     time, so switching also clears the jit caches — otherwise already-compiled
     programs would silently keep their old backend forever."""
     if name not in MULTISEARCH_BACKENDS:
@@ -55,9 +56,7 @@ def set_multisearch_backend(name: str) -> None:
 
 def multisearch_backend() -> str:
     """The backend ``multisearch_bounds`` resolves to right now."""
-    if _backend != "auto":
-        return _backend
-    return "pallas" if jax.default_backend() == "tpu" else "xla"
+    return "xla" if _backend == "auto" else _backend
 
 
 # XLA binary-search flavor. Every method computes identical insertion
@@ -75,11 +74,11 @@ def multisearch_bounds(sorted_keys: Array, queries: Array) -> tuple[Array, Array
     """(count_lt, count_le) per query: the searchsorted left/right insertion
     points into ``sorted_keys``, int32, answered in one fused multisearch.
 
-    This is the backend-dispatched hot-path primitive: on TPU (or with the
-    backend forced to "pallas") it runs the chunked counting kernel from
-    ``repro.kernels.multisearch`` — dense compare-reduce in VMEM, zero gathers,
-    both bounds from the same streaming pass over the keys; otherwise two
-    ``jnp.searchsorted`` binary searches.
+    This is the backend-dispatched hot-path primitive: by default two
+    ``jnp.searchsorted`` binary searches; with the backend forced to "pallas"
+    the chunked counting kernel from ``repro.kernels.multisearch`` — dense
+    compare-reduce in VMEM, zero gathers, both bounds from the same streaming
+    pass over the keys.
     """
     if multisearch_backend() == "pallas":
         from repro.kernels.ops import multisearch_counts_op

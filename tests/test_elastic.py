@@ -125,6 +125,19 @@ class TestElasticBank:
         assert float(ests[bank.slot_of("a")]) == float(ref_a.estimate()[0])
         assert float(ests[bank.slot_of("b")]) == float(ref_b.estimate()[0])
 
+    def test_step_cursors_reach_the_device_as_a_copy(self):
+        """On the CPU backend jnp.asarray aliases a 64-byte-aligned host
+        buffer instead of copying it, and dispatch is asynchronous: bumping
+        a slot's cursor right after an ingest dispatch must not change the
+        cursors that dispatch reads (it did, now and then, under load)."""
+        bank = ElasticBankEngine(R, S, capacity=2, backend="single")
+        buf = np.zeros(16, np.int64)
+        off = (-buf.ctypes.data % 64) // 8
+        bank._steps = buf[off:off + 2]  # aligned, so aliasable
+        cursors = bank._cursors()
+        bank._steps[0] += 5
+        assert int(cursors[0]) == 0
+
     def test_snapshot_restore_under_concurrent_ingest(self):
         """Freeze tenant a, keep feeding b, evict a, restore a: a's state is
         bit-exact at its snapshot point and b never noticed."""

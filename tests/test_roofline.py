@@ -80,8 +80,6 @@ class TestAnalyticFlops:
             return logits_fn(p, cfg, h)
 
         ca = jax.jit(fwd).lower(params, toks).compile().cost_analysis()
-        if isinstance(ca, (list, tuple)):  # jax < 0.6 wraps the dict in a list
-            ca = ca[0]
         hlo = float(ca["flops"])
         analytic = lm_flops(cfg, "prefill", B, S) + (
             2 * B * S * cfg.d_model * cfg.vocab - 2 * B * cfg.d_model * cfg.vocab
