@@ -120,7 +120,6 @@ def main() -> None:
 
     from benchmarks import (
         accuracy,
-        breakdown,
         kernels,
         multistream,
         query_serve,
@@ -133,7 +132,6 @@ def main() -> None:
         "accuracy": accuracy.main,      # paper Table 2
         "throughput": throughput.main,  # paper Figure 6
         "schemes": schemes.main,        # paper Table 3 / Section 1
-        "breakdown": breakdown.main,    # paper Figure 5
         "kernels": kernels.main,        # kernel contracts + bytes
         "multistream": multistream.main,  # engine multi-tenant bank
         "query_serve": query_serve.main,  # queries/s under concurrent ingest

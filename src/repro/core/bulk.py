@@ -46,22 +46,24 @@ def step1_level1(state: EstimatorState, W, n_valid, key):
     Draw t ~ U[0, m + |W|); t >= m selects replacement edge W[t - m]. For batch
     size 1 this is exactly classic reservoir sampling.
     """
-    r = state.r
-    m = state.m_seen
-    total = m + n_valid.astype(jnp.int64)
-    t = jax.random.randint(
-        key, (r,), jnp.int64(0), jnp.maximum(total, 1), dtype=jnp.int64
-    )
-    replace = (t >= m) & (total > 0)
-    idx = jnp.clip(t - m, 0, jnp.maximum(n_valid.astype(jnp.int64) - 1, 0)).astype(
-        jnp.int32
-    )
-    f1 = jnp.where(replace[:, None], W[idx], state.f1)
-    chi = jnp.where(replace, 0, state.chi)
-    f2 = jnp.where(replace[:, None], jnp.int32(-1), state.f2)
-    has_f3 = state.has_f3 & ~replace
-    f1_bpos = jnp.where(replace, idx, -1)  # ephemeral: position of f1 within W
-    return f1, chi, f2, has_f3, f1_bpos
+    with jax.named_scope("step1"):
+        r = state.r
+        m = state.m_seen
+        total = m + n_valid.astype(jnp.int64)
+        with jax.named_scope("rng"):
+            t = jax.random.randint(
+                key, (r,), jnp.int64(0), jnp.maximum(total, 1), dtype=jnp.int64
+            )
+        replace = (t >= m) & (total > 0)
+        idx = jnp.clip(
+            t - m, 0, jnp.maximum(n_valid.astype(jnp.int64) - 1, 0)
+        ).astype(jnp.int32)
+        f1 = jnp.where(replace[:, None], W[idx], state.f1)
+        chi = jnp.where(replace, 0, state.chi)
+        f2 = jnp.where(replace[:, None], jnp.int32(-1), state.f2)
+        has_f3 = state.has_f3 & ~replace
+        f1_bpos = jnp.where(replace, idx, -1)  # ephemeral: position of f1 within W
+        return f1, chi, f2, has_f3, f1_bpos
 
 
 def rank_queries(R: RankStructure, u, v, f1_bpos):
@@ -82,26 +84,28 @@ def rank_queries(R: RankStructure, u, v, f1_bpos):
     and v) ride in one concatenated query vector: one pass over the structure
     answers everything.
     """
-    s = R.s
-    zero = jnp.zeros_like(f1_bpos)
-    q = jnp.concatenate(
-        [
-            pack2(u, (s - 1) - f1_bpos),  # fresh: own arc; old: segment end
-            pack2(v, (s - 1) - f1_bpos),
-            pack2(u, zero),  # segment starts
-            pack2(v, zero),
-        ]
-    )
-    lt, le = multisearch_bounds(R.key_desc, q)
-    r = u.shape[0]
-    hi_u, hi_v, lo_u, lo_v = lt[:r], lt[r : 2 * r], lt[2 * r : 3 * r], lt[3 * r :]
-    w_u = (hi_u - lo_u).astype(jnp.int32)
-    w_v = (hi_v - lo_v).astype(jnp.int32)
-    # a fresh f1's own arc is guaranteed present; mask anyway (belt + braces)
-    fresh = f1_bpos >= 0
-    miss_u = fresh & ~(le[:r] > hi_u)
-    miss_v = fresh & ~(le[r : 2 * r] > hi_v)
-    return jnp.where(miss_u, 0, w_u), jnp.where(miss_v, 0, w_v)
+    with jax.named_scope("q1"):
+        s = R.s
+        zero = jnp.zeros_like(f1_bpos)
+        q = jnp.concatenate(
+            [
+                pack2(u, (s - 1) - f1_bpos),  # fresh: own arc; old: segment end
+                pack2(v, (s - 1) - f1_bpos),
+                pack2(u, zero),  # segment starts
+                pack2(v, zero),
+            ]
+        )
+        lt, le = multisearch_bounds(R.key_desc, q)
+        r = u.shape[0]
+        hi_u, hi_v = lt[:r], lt[r : 2 * r]
+        lo_u, lo_v = lt[2 * r : 3 * r], lt[3 * r :]
+        w_u = (hi_u - lo_u).astype(jnp.int32)
+        w_v = (hi_v - lo_v).astype(jnp.int32)
+        # a fresh f1's own arc is guaranteed present; mask anyway (belt + braces)
+        fresh = f1_bpos >= 0
+        miss_u = fresh & ~(le[:r] > hi_u)
+        miss_v = fresh & ~(le[r : 2 * r] > hi_v)
+        return jnp.where(miss_u, 0, w_u), jnp.where(miss_v, 0, w_v)
 
 
 def step2_level2(f1, chi_minus, f2, has_f3, f1_bpos, R: RankStructure, key):
@@ -116,7 +120,8 @@ def step2_level2(f1, chi_minus, f2, has_f3, f1_bpos, R: RankStructure, key):
     chi_new = chi_minus + chi_plus
 
     k_coin, k_phi = jax.random.split(key)
-    coin = jax.random.uniform(k_coin, (f1.shape[0],), dtype=jnp.float32)
+    with jax.named_scope("rng"):
+        coin = jax.random.uniform(k_coin, (f1.shape[0],), dtype=jnp.float32)
     p_new = chi_plus.astype(jnp.float32) / jnp.maximum(
         chi_new.astype(jnp.float32), 1.0
     )
@@ -124,24 +129,26 @@ def step2_level2(f1, chi_minus, f2, has_f3, f1_bpos, R: RankStructure, key):
 
     # draw phi in [0, chi+) and decode via the (src, rank) naming system:
     # one Q2 multisearch over key_rank
-    phi = jax.random.randint(
-        k_phi, (f1.shape[0],), 0, jnp.maximum(chi_plus, 1), dtype=jnp.int32
-    )
-    t_src = jnp.where(phi < ld, u, v)
-    t_rank = jnp.where(phi < ld, phi, phi - ld)
-    lt, le = multisearch_bounds(R.key_rank, pack2(t_src, t_rank))
-    found = le > lt
-    j = jnp.minimum(lt, R.key_rank.shape[0] - 1)
-    cand_a, cand_b = R.src[j], R.dst[j]
-    cand = jnp.stack(
-        [jnp.minimum(cand_a, cand_b), jnp.maximum(cand_a, cand_b)], axis=-1
-    )
-    cand_pos = R.pos[j]
-    take_new = take_new & found  # found is guaranteed when chi_plus>0; belt+braces
+    with jax.named_scope("rng"):
+        phi = jax.random.randint(
+            k_phi, (f1.shape[0],), 0, jnp.maximum(chi_plus, 1), dtype=jnp.int32
+        )
+    with jax.named_scope("q2"):
+        t_src = jnp.where(phi < ld, u, v)
+        t_rank = jnp.where(phi < ld, phi, phi - ld)
+        lt, le = multisearch_bounds(R.key_rank, pack2(t_src, t_rank))
+        found = le > lt
+        j = jnp.minimum(lt, R.key_rank.shape[0] - 1)
+        cand_a, cand_b = R.src[j], R.dst[j]
+        cand = jnp.stack(
+            [jnp.minimum(cand_a, cand_b), jnp.maximum(cand_a, cand_b)], axis=-1
+        )
+        cand_pos = R.pos[j]
+        take_new = take_new & found  # guaranteed when chi_plus>0; belt+braces
 
-    f2_new = jnp.where(take_new[:, None], cand, f2)
-    f2_bpos = jnp.where(take_new, cand_pos, -1)  # ephemeral
-    has_f3 = has_f3 & ~take_new
+        f2_new = jnp.where(take_new[:, None], cand, f2)
+        f2_bpos = jnp.where(take_new, cand_pos, -1)  # ephemeral
+        has_f3 = has_f3 & ~take_new
     return f2_new, chi_new, has_f3, f2_bpos
 
 
@@ -153,25 +160,27 @@ def step3_closing(f1, f2, has_f3, f2_bpos, R: RankStructure):
     batch pos > p2; for older f2 any batch pos qualifies (f2_bpos = -1). One
     multisearch over the (min,max)-sorted batch answers every estimator.
     """
-    u, v = f1[:, 0], f1[:, 1]
-    a, b = f2[:, 0], f2[:, 1]
-    have_wedge = (u >= 0) & (a >= 0)
+    with jax.named_scope("closing"):
+        u, v = f1[:, 0], f1[:, 1]
+        a, b = f2[:, 0], f2[:, 1]
+        have_wedge = (u >= 0) & (a >= 0)
 
-    u_shared = (u == a) | (u == b)
-    o1 = jnp.where(u_shared, v, u)
-    a_shared = (a == u) | (a == v)
-    o2 = jnp.where(a_shared, b, a)
-    cmin = jnp.minimum(o1, o2)
-    cmax = jnp.maximum(o1, o2)
+        u_shared = (u == a) | (u == b)
+        o1 = jnp.where(u_shared, v, u)
+        a_shared = (a == u) | (a == v)
+        o2 = jnp.where(a_shared, b, a)
+        cmin = jnp.minimum(o1, o2)
+        cmax = jnp.maximum(o1, o2)
 
-    lt, le = multisearch_bounds(R.ekey, pack2(cmin, cmax))
-    found = le > lt
-    # the arrival rule is existential — ANY copy after f2 closes the wedge —
-    # so on duplicate-edge (multigraph) batches take the LAST copy's pos: the
-    # sort is stable, so the duplicate run [lt, le) is pos-ascending
-    p3 = R.epos[jnp.maximum(le - 1, 0)]
-    closed_now = have_wedge & found & (p3 > f2_bpos)
-    return has_f3 | closed_now
+        lt, le = multisearch_bounds(R.ekey, pack2(cmin, cmax))
+        found = le > lt
+        # the arrival rule is existential — ANY copy after f2 closes the
+        # wedge — so on duplicate-edge (multigraph) batches take the LAST
+        # copy's pos: the sort is stable, so the duplicate run [lt, le) is
+        # pos-ascending
+        p3 = R.epos[jnp.maximum(le - 1, 0)]
+        closed_now = have_wedge & found & (p3 > f2_bpos)
+        return has_f3 | closed_now
 
 
 def bulk_update_all(
@@ -244,31 +253,32 @@ def _chunk_randomness(state: EstimatorState, n_valids, key, steps):
     Returns (m_before (K,), totals (K,), t (K,r), coin (K,r),
     phi_hi (K,r), phi_lo (K,r)).
     """
-    r = state.r
-    nv64 = n_valids.astype(jnp.int64)
-    m_before = state.m_seen + jnp.cumsum(nv64) - nv64
-    totals = m_before + nv64
+    with jax.named_scope("rng"):
+        r = state.r
+        nv64 = n_valids.astype(jnp.int64)
+        m_before = state.m_seen + jnp.cumsum(nv64) - nv64
+        totals = m_before + nv64
 
-    bkeys = jax.vmap(lambda i: jax.random.fold_in(key, i))(steps)
-    k12 = jax.vmap(jax.random.split)(bkeys)  # bulk_update_all's (k1, k2)
-    kcp = jax.vmap(jax.random.split)(k12[:, 1])  # step2's (k_coin, k_phi)
-    kbits = jax.vmap(jax.random.split)(kcp[:, 1])  # randint's internal split
+        bkeys = jax.vmap(lambda i: jax.random.fold_in(key, i))(steps)
+        k12 = jax.vmap(jax.random.split)(bkeys)  # bulk_update_all's (k1, k2)
+        kcp = jax.vmap(jax.random.split)(k12[:, 1])  # step2's (k_coin, k_phi)
+        kbits = jax.vmap(jax.random.split)(kcp[:, 1])  # randint's internal split
 
-    t = jax.vmap(
-        lambda k, total: jax.random.randint(
-            k, (r,), jnp.int64(0), jnp.maximum(total, 1), dtype=jnp.int64
+        t = jax.vmap(
+            lambda k, total: jax.random.randint(
+                k, (r,), jnp.int64(0), jnp.maximum(total, 1), dtype=jnp.int64
+            )
+        )(k12[:, 0], totals)
+        coin = jax.vmap(
+            lambda k: jax.random.uniform(k, (r,), dtype=jnp.float32)
+        )(kcp[:, 0])
+        phi_hi = jax.vmap(lambda k: jax.random.bits(k, (r,), jnp.uint32))(
+            kbits[:, 0]
         )
-    )(k12[:, 0], totals)
-    coin = jax.vmap(
-        lambda k: jax.random.uniform(k, (r,), dtype=jnp.float32)
-    )(kcp[:, 0])
-    phi_hi = jax.vmap(lambda k: jax.random.bits(k, (r,), jnp.uint32))(
-        kbits[:, 0]
-    )
-    phi_lo = jax.vmap(lambda k: jax.random.bits(k, (r,), jnp.uint32))(
-        kbits[:, 1]
-    )
-    return m_before, totals, t, coin, phi_hi, phi_lo
+        phi_lo = jax.vmap(lambda k: jax.random.bits(k, (r,), jnp.uint32))(
+            kbits[:, 1]
+        )
+        return m_before, totals, t, coin, phi_hi, phi_lo
 
 
 def _step2_fused(f1, chi_minus, f2, has_f3, f1_bpos, R: RankStructure,
@@ -284,20 +294,21 @@ def _step2_fused(f1, chi_minus, f2, has_f3, f1_bpos, R: RankStructure,
     """
     u, v = f1[:, 0], f1[:, 1]
     have_f1 = u >= 0
-    s = R.s
-    zero = jnp.zeros_like(f1_bpos)
-    q = jnp.concatenate(
-        [
-            pack2(u, (s - 1) - f1_bpos),
-            pack2(v, (s - 1) - f1_bpos),
-            pack2(u, zero),
-            pack2(v, zero),
-        ]
-    )
-    lt4 = multisearch_lt(R.key_desc, q)
-    r = u.shape[0]
-    ld = (lt4[:r] - lt4[2 * r : 3 * r]).astype(jnp.int32)
-    rd = (lt4[r : 2 * r] - lt4[3 * r :]).astype(jnp.int32)
+    with jax.named_scope("q1"):
+        s = R.s
+        zero = jnp.zeros_like(f1_bpos)
+        q = jnp.concatenate(
+            [
+                pack2(u, (s - 1) - f1_bpos),
+                pack2(v, (s - 1) - f1_bpos),
+                pack2(u, zero),
+                pack2(v, zero),
+            ]
+        )
+        lt4 = multisearch_lt(R.key_desc, q)
+        r = u.shape[0]
+        ld = (lt4[:r] - lt4[2 * r : 3 * r]).astype(jnp.int32)
+        rd = (lt4[r : 2 * r] - lt4[3 * r :]).astype(jnp.int32)
     ld = jnp.where(have_f1, ld, 0)
     rd = jnp.where(have_f1, rd, 0)
     chi_plus = ld + rd
@@ -308,24 +319,26 @@ def _step2_fused(f1, chi_minus, f2, has_f3, f1_bpos, R: RankStructure,
     )
     take_new = have_f1 & (chi_plus > 0) & (coin < p_new)
 
-    phi = randint_from_bits(phi_hi, phi_lo, jnp.maximum(chi_plus, 1))
-    t_src = jnp.where(phi < ld, u, v)
-    t_rank = jnp.where(phi < ld, phi, phi - ld)
-    qk = pack2(t_src, t_rank)
-    n2 = R.key_rank.shape[0]
-    lt = multisearch_lt(R.key_rank, qk)
-    j = jnp.minimum(lt, n2 - 1)
-    found = (lt < n2) & (R.key_rank[j] == qk)
-    cand_a, cand_b = R.src[j], R.dst[j]
-    cand = jnp.stack(
-        [jnp.minimum(cand_a, cand_b), jnp.maximum(cand_a, cand_b)], axis=-1
-    )
-    cand_pos = R.pos[j]
-    take_new = take_new & found
+    with jax.named_scope("rng"):
+        phi = randint_from_bits(phi_hi, phi_lo, jnp.maximum(chi_plus, 1))
+    with jax.named_scope("q2"):
+        t_src = jnp.where(phi < ld, u, v)
+        t_rank = jnp.where(phi < ld, phi, phi - ld)
+        qk = pack2(t_src, t_rank)
+        n2 = R.key_rank.shape[0]
+        lt = multisearch_lt(R.key_rank, qk)
+        j = jnp.minimum(lt, n2 - 1)
+        found = (lt < n2) & (R.key_rank[j] == qk)
+        cand_a, cand_b = R.src[j], R.dst[j]
+        cand = jnp.stack(
+            [jnp.minimum(cand_a, cand_b), jnp.maximum(cand_a, cand_b)], axis=-1
+        )
+        cand_pos = R.pos[j]
+        take_new = take_new & found
 
-    f2_new = jnp.where(take_new[:, None], cand, f2)
-    f2_bpos = jnp.where(take_new, cand_pos, -1)
-    has_f3 = has_f3 & ~take_new
+        f2_new = jnp.where(take_new[:, None], cand, f2)
+        f2_bpos = jnp.where(take_new, cand_pos, -1)
+        has_f3 = has_f3 & ~take_new
     return f2_new, chi_new, has_f3, f2_bpos
 
 
@@ -355,12 +368,13 @@ def _bulk_update_chunk_fused(
     # hoisted step-1 selects: the reservoir decisions are deterministic in
     # (t, m_seen trajectory), and m_seen's trajectory is just a cumsum
     nv64 = n_valids.astype(jnp.int64)
-    replace = (t >= m_before[:, None]) & (totals[:, None] > 0)
-    idx = jnp.clip(
-        t - m_before[:, None], 0, jnp.maximum(nv64 - 1, 0)[:, None]
-    ).astype(jnp.int32)
-    w_sel = jax.vmap(lambda W, ix: W[ix])(Ws, idx)  # (K, r, 2)
-    f1_bpos = jnp.where(replace, idx, -1)
+    with jax.named_scope("step1"):
+        replace = (t >= m_before[:, None]) & (totals[:, None] > 0)
+        idx = jnp.clip(
+            t - m_before[:, None], 0, jnp.maximum(nv64 - 1, 0)[:, None]
+        ).astype(jnp.int32)
+        w_sel = jax.vmap(lambda W, ix: W[ix])(Ws, idx)  # (K, r, 2)
+        f1_bpos = jnp.where(replace, idx, -1)
 
     R = rank_all_chunk(Ws, n_valids, use_kernels=use_kernels)
     m_out = state.m_seen + jnp.sum(nv64)
@@ -380,10 +394,11 @@ def _bulk_update_chunk_fused(
     def step(carry, xs):
         f1, chi, f2, has_f3 = carry
         rep, wsel, f1b, cn, hb, lb, Rk = xs
-        f1 = jnp.where(rep[:, None], wsel, f1)
-        chi_m = jnp.where(rep, 0, chi)
-        f2 = jnp.where(rep[:, None], jnp.int32(-1), f2)
-        has_f3 = has_f3 & ~rep
+        with jax.named_scope("step1"):
+            f1 = jnp.where(rep[:, None], wsel, f1)
+            chi_m = jnp.where(rep, 0, chi)
+            f2 = jnp.where(rep[:, None], jnp.int32(-1), f2)
+            has_f3 = has_f3 & ~rep
         f2, chi, has_f3, f2_bpos = _step2_fused(
             f1, chi_m, f2, has_f3, f1b, Rk, cn, hb, lb
         )
@@ -502,9 +517,10 @@ def bulk_delete_update(
     stream cursor, which is what keeps all-insertion turnstile streams
     bit-identical to the insertion-only path.
     """
-    dkey = delete_keys(D, n_valid)
-    lt, le = multisearch_bounds(dkey, _delete_queries(state))
-    return _apply_delete_hits(state, le > lt)
+    with jax.named_scope("delete"):
+        dkey = delete_keys(D, n_valid)
+        lt, le = multisearch_bounds(dkey, _delete_queries(state))
+        return _apply_delete_hits(state, le > lt)
 
 
 def _delete_queries(state: EstimatorState) -> jax.Array:
@@ -578,18 +594,19 @@ def bulk_delete_chunk(
         state, _ = jax.lax.scan(step, state, (Ds, n_valids))
         return state
 
-    dkeys = jax.vmap(delete_keys)(Ds, n_valids)  # (K, s) hoisted sorts
-    n = dkeys.shape[1]
+    with jax.named_scope("delete"):
+        dkeys = jax.vmap(delete_keys)(Ds, n_valids)  # (K, s) hoisted sorts
+        n = dkeys.shape[1]
 
-    def step(st, dk):
-        q = _delete_queries(st)
-        lt = multisearch_lt(dk, q)
-        j = jnp.minimum(lt, n - 1)
-        hit = (lt < n) & (dk[j] == q)
-        return _apply_delete_hits(st, hit), None
+        def step(st, dk):
+            q = _delete_queries(st)
+            lt = multisearch_lt(dk, q)
+            j = jnp.minimum(lt, n - 1)
+            hit = (lt < n) & (dk[j] == q)
+            return _apply_delete_hits(st, hit), None
 
-    state, _ = jax.lax.scan(step, state, dkeys)
-    return state
+        state, _ = jax.lax.scan(step, state, dkeys)
+        return state
 
 
 bulk_delete_chunk_jit = jax.jit(bulk_delete_chunk, donate_argnums=(0,))

@@ -76,10 +76,11 @@ def estimate(state: EstimatorState, groups: int = 9) -> jax.Array:
     ``groups`` that does not divide ``r`` is rounded down to
     ``effective_groups(r, groups)`` — see the module docstring for the rule.
     """
-    x = coarse_estimates(state)
-    r = x.shape[0]
-    g = effective_groups(r, groups)
-    return jnp.median(jnp.mean(x.reshape(g, r // g), axis=1))
+    with jax.named_scope("estimate"):
+        x = coarse_estimates(state)
+        r = x.shape[0]
+        g = effective_groups(r, groups)
+        return jnp.median(jnp.mean(x.reshape(g, r // g), axis=1))
 
 
 def partial_group_sums(
@@ -91,9 +92,10 @@ def partial_group_sums(
     ``r // g``, so a shard may straddle a group boundary — each element lands
     in the bin its *global* index names; bins the shard does not touch stay
     exactly 0.0 and contribute nothing to the combine."""
-    g = effective_groups(r, groups)
-    gid = (offset + jnp.arange(x_local.shape[0])) // (r // g)
-    return jnp.zeros((g,), jnp.float64).at[gid].add(x_local)
+    with jax.named_scope("estimate"):
+        g = effective_groups(r, groups)
+        gid = (offset + jnp.arange(x_local.shape[0])) // (r // g)
+        return jnp.zeros((g,), jnp.float64).at[gid].add(x_local)
 
 
 def combine_group_sums(partials: jax.Array, r: int, groups: int) -> jax.Array:
@@ -103,8 +105,9 @@ def combine_group_sums(partials: jax.Array, r: int, groups: int) -> jax.Array:
     leading axis; dividing by the group size and taking the median then
     reproduces ``estimate`` exactly (see "Shardable decomposition" in the
     module docstring for why the split point cannot change the value)."""
-    g = effective_groups(r, groups)
-    return jnp.median(jnp.sum(partials, axis=0) / (r // g))
+    with jax.named_scope("estimate"):
+        g = effective_groups(r, groups)
+        return jnp.median(jnp.sum(partials, axis=0) / (r // g))
 
 
 estimate_jit = jax.jit(estimate, static_argnums=(1,))

@@ -58,45 +58,46 @@ class RankStructure(NamedTuple):
 
 def rank_all(W: jax.Array, n_valid: jax.Array) -> RankStructure:
     """Build the RankStructure for batch ``W`` ((s,2) int32, first n_valid real)."""
-    s = W.shape[0]
-    pos1 = jnp.arange(s, dtype=jnp.int32)
-    valid_e = pos1 < n_valid
+    with jax.named_scope("rank_all"):
+        s = W.shape[0]
+        pos1 = jnp.arange(s, dtype=jnp.int32)
+        valid_e = pos1 < n_valid
 
-    # --- directed arcs, both orientations (paper: map + concat) ---
-    src = jnp.concatenate([W[:, 0], W[:, 1]])
-    dst = jnp.concatenate([W[:, 1], W[:, 0]])
-    pos = jnp.concatenate([pos1, pos1])
-    valid_a = jnp.concatenate([valid_e, valid_e])
+        # --- directed arcs, both orientations (paper: map + concat) ---
+        src = jnp.concatenate([W[:, 0], W[:, 1]])
+        dst = jnp.concatenate([W[:, 1], W[:, 0]])
+        pos = jnp.concatenate([pos1, pos1])
+        valid_a = jnp.concatenate([valid_e, valid_e])
 
-    # sort by (src asc, pos desc): minor key = s-1-pos
-    kd = pack2(src, (s - 1) - pos)
-    kd = jnp.where(valid_a, kd, INF64)
-    kd_s, src_s, dst_s, pos_s = sort_by_key(kd, src, dst, pos)
+        # sort by (src asc, pos desc): minor key = s-1-pos
+        kd = pack2(src, (s - 1) - pos)
+        kd = jnp.where(valid_a, kd, INF64)
+        kd_s, src_s, dst_s, pos_s = sort_by_key(kd, src, dst, pos)
 
-    # rank = offset within src segment (scan-with-reset over the sorted arcs)
-    starts = segment_starts(src_s.astype(jnp.int64))
-    rank_s = segmented_iota(starts)
+        # rank = offset within src segment (scan-with-reset over the sorted arcs)
+        starts = segment_starts(src_s.astype(jnp.int64))
+        rank_s = segmented_iota(starts)
 
-    kr = pack2(src_s, rank_s)
-    n_valid_a = 2 * n_valid
-    kr = jnp.where(jnp.arange(2 * s) < n_valid_a, kr, INF64)
+        kr = pack2(src_s, rank_s)
+        n_valid_a = 2 * n_valid
+        kr = jnp.where(jnp.arange(2 * s) < n_valid_a, kr, INF64)
 
-    # --- closing-edge index: canonical (min,max) sorted edges ---
-    emin = jnp.minimum(W[:, 0], W[:, 1])
-    emax = jnp.maximum(W[:, 0], W[:, 1])
-    ek = jnp.where(valid_e, pack2(emin, emax), INF64)
-    ek_s, epos_s = sort_by_key(ek, pos1)
+        # --- closing-edge index: canonical (min,max) sorted edges ---
+        emin = jnp.minimum(W[:, 0], W[:, 1])
+        emax = jnp.maximum(W[:, 0], W[:, 1])
+        ek = jnp.where(valid_e, pack2(emin, emax), INF64)
+        ek_s, epos_s = sort_by_key(ek, pos1)
 
-    return RankStructure(
-        key_desc=kd_s,
-        key_rank=kr,
-        src=src_s,
-        dst=dst_s,
-        pos=pos_s,
-        rank=rank_s,
-        ekey=ek_s,
-        epos=epos_s,
-    )
+        return RankStructure(
+            key_desc=kd_s,
+            key_rank=kr,
+            src=src_s,
+            dst=dst_s,
+            pos=pos_s,
+            rank=rank_s,
+            ekey=ek_s,
+            epos=epos_s,
+        )
 
 
 def rank_all_chunk(
@@ -124,7 +125,8 @@ def rank_all_chunk(
     n_valids = jnp.asarray(n_valids, dtype=jnp.int32)
     if not use_kernels:
         return jax.vmap(rank_all)(Ws, n_valids)
-    return _rank_all_chunk_kernels(Ws, n_valids)
+    with jax.named_scope("rank_all"):
+        return _rank_all_chunk_kernels(Ws, n_valids)
 
 
 def _next_pow2(n: int) -> int:

@@ -308,44 +308,45 @@ class LocalScheme(EstimatorScheme):
         ``offset + i`` — pool membership is a function of the global index,
         so a shard straddling a pool boundary attributes each estimator to
         its own pool regardless of where the shard cut falls)."""
-        r_pool = r // self.n_pools
-        x = coarse_estimates(state)  # (r_local,) f64, E[X] = tau each
-        u, v = state.f1[:, 0], state.f1[:, 1]
-        a, b = state.f2[:, 0], state.f2[:, 1]
-        # the sampled triangle's third vertex: f2's endpoint not shared with f1
-        o2 = jnp.where((a == u) | (a == v), b, a)
-        tri = jnp.stack([u, v, o2])  # (3, r_local) — the triangle's vertices
+        with jax.named_scope("estimate"):
+            r_pool = r // self.n_pools
+            x = coarse_estimates(state)  # (r_local,) f64, E[X] = tau each
+            u, v = state.f1[:, 0], state.f1[:, 1]
+            a, b = state.f2[:, 0], state.f2[:, 1]
+            # the sampled triangle's third vertex: f2's endpoint not shared with f1
+            o2 = jnp.where((a == u) | (a == v), b, a)
+            tri = jnp.stack([u, v, o2])  # (3, r_local) — the triangle's vertices
 
-        r_local = state.chi.shape[0]
-        pool = (
-            (offset + jnp.arange(r_local, dtype=jnp.int32)) // r_pool
-        ).astype(jnp.int32)
-        closed = state.has_f3 & (u >= 0) & (a >= 0)
-        take = (
-            closed[None, :]
-            & (tri >= 0)
-            & (tri < self.n_vertices)
-            & (vertex_pool(tri, self.n_pools) == pool[None, :])
-        )
-        vert = jnp.where(take, tri, self.n_vertices)  # out of bounds -> drop
-        vals = jnp.where(take, x[None, :], 0.0)
-        if ingest_backend() == "pallas":
-            # kernel path: the scatter as a segment_sum (kernels/segment_sum
-            # one-hot MXU form). Bit-exact vs .at[].add: coarse estimates are
-            # integer-valued f64 (chi * m_seen), so every partial sum here is
-            # exact (< 2**53) and summation order cannot matter.
-            from repro.kernels.ops import segment_sum_op
+            r_local = state.chi.shape[0]
+            pool = (
+                (offset + jnp.arange(r_local, dtype=jnp.int32)) // r_pool
+            ).astype(jnp.int32)
+            closed = state.has_f3 & (u >= 0) & (a >= 0)
+            take = (
+                closed[None, :]
+                & (tri >= 0)
+                & (tri < self.n_vertices)
+                & (vertex_pool(tri, self.n_pools) == pool[None, :])
+            )
+            vert = jnp.where(take, tri, self.n_vertices)  # out of bounds -> drop
+            vals = jnp.where(take, x[None, :], 0.0)
+            if ingest_backend() == "pallas":
+                # kernel path: the scatter as a segment_sum (kernels/segment_sum
+                # one-hot MXU form). Bit-exact vs .at[].add: coarse estimates are
+                # integer-valued f64 (chi * m_seen), so every partial sum here is
+                # exact (< 2**53) and summation order cannot matter.
+                from repro.kernels.ops import segment_sum_op
 
-            return segment_sum_op(
-                vals.reshape(-1)[:, None],
-                vert.reshape(-1).astype(jnp.int32),
-                self.n_vertices,
-            )[:, 0]
-        return (
-            jnp.zeros((self.n_vertices,), jnp.float64)
-            .at[vert]
-            .add(vals, mode="drop")
-        )
+                return segment_sum_op(
+                    vals.reshape(-1)[:, None],
+                    vert.reshape(-1).astype(jnp.int32),
+                    self.n_vertices,
+                )[:, 0]
+            return (
+                jnp.zeros((self.n_vertices,), jnp.float64)
+                .at[vert]
+                .add(vals, mode="drop")
+            )
 
     def estimate(self, state, groups: int = 9) -> jax.Array:
         del groups  # see class docstring: pool mean, not median-of-means

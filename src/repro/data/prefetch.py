@@ -29,6 +29,7 @@ import threading
 from typing import Callable, Iterable, Iterator, Optional
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 
 _DONE = object()  # sentinel distinct from any legitimate batch (even None)
@@ -90,8 +91,12 @@ class PrefetchQueue:
 
     def _produce(self, source):
         try:
-            seq = 0
-            for item in source:
+            source, seq = iter(source), 0
+            while True:
+                with TraceAnnotation("repro.prefetch.produce", seq=seq):
+                    item = next(source, _DONE)
+                if item is _DONE:
+                    break
                 kind = self._source_fault()
                 self.q.put((seq, item))
                 if kind == "duplicate":

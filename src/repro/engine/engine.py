@@ -105,6 +105,7 @@ from typing import Any, Iterable, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.distributed import auto_axes
 from repro.core.estimate import effective_groups
@@ -351,7 +352,8 @@ class TriangleCountEngine:
 
     def edges_seen(self) -> np.ndarray:
         """(n_tenants,) int64: stream length ingested per tenant."""
-        m = np.asarray(self._state.m_seen)
+        with TraceAnnotation("repro.engine.wait", step=self._step):
+            m = np.asarray(self._state.m_seen)
         return m if m.ndim else np.broadcast_to(m, (self.n_tenants,)).copy()
 
     # -- ingestion ----------------------------------------------------------
@@ -382,39 +384,45 @@ class TriangleCountEngine:
         inferred count (scalar or per-tenant) when W is pre-padded.
         """
         check_fault("engine.ingest")  # chaos site: fires before any mutation
-        W = np.asarray(W)
-        T = self.n_tenants
-        if W.ndim == 2:
-            Wp, n = self._pad(W)
-            nv = np.full((T,), n if n_valid is None else int(n_valid), np.int32)
-            Wb = np.broadcast_to(Wp[None], (T,) + Wp.shape)
-        elif W.ndim == 3:
-            if W.shape[0] != T:
-                raise ValueError(f"got {W.shape[0]} tenant batches for {T} tenants")
-            padded = [self._pad(W[t]) for t in range(T)]
-            Wb = np.stack([p[0] for p in padded])
-            if n_valid is None:
-                nv = np.array([p[1] for p in padded], np.int32)
+        with TraceAnnotation("repro.engine.stage", step=self._step):
+            W = np.asarray(W)
+            T = self.n_tenants
+            if W.ndim == 2:
+                Wp, n = self._pad(W)
+                nv = np.full((T,), n if n_valid is None else int(n_valid), np.int32)
+                Wb = np.broadcast_to(Wp[None], (T,) + Wp.shape)
+            elif W.ndim == 3:
+                if W.shape[0] != T:
+                    raise ValueError(
+                        f"got {W.shape[0]} tenant batches for {T} tenants"
+                    )
+                padded = [self._pad(W[t]) for t in range(T)]
+                Wb = np.stack([p[0] for p in padded])
+                if n_valid is None:
+                    nv = np.array([p[1] for p in padded], np.int32)
+                else:
+                    nv = np.broadcast_to(np.asarray(n_valid, np.int32), (T,)).copy()
             else:
-                nv = np.broadcast_to(np.asarray(n_valid, np.int32), (T,)).copy()
-        else:
-            raise ValueError(f"W must be (s,2) or (T,s,2), got {W.shape}")
+                raise ValueError(f"W must be (s,2) or (T,s,2), got {W.shape}")
 
-        Wb_host, nv_host = Wb, nv  # window clock reads these after dispatch
-        keys = jax.vmap(jax.random.fold_in, in_axes=(0, None))(
-            self._root_keys, self._step
-        )
-        if not self.plan.banked:  # distributed single-tenant backends
-            Wb, nv, keys = Wb[0], jnp.int32(int(nv[0])), keys[0]
-            Wb = jnp.asarray(Wb)
-        elif self.plan.batch_w_sharding is not None:
-            # host -> shards in one copy (no staging hop via the default device)
-            Wb = jax.device_put(
-                Wb, self.plan.batch_w_sharding(self.config, self.mesh)
+            Wb_host, nv_host = Wb, nv  # window clock reads these after dispatch
+            keys = jax.vmap(jax.random.fold_in, in_axes=(0, None))(
+                self._root_keys, self._step
             )
-        else:
-            Wb = jnp.asarray(Wb)
-        out = self._update(self._state, Wb, jnp.asarray(nv), keys)
+            if not self.plan.banked:  # distributed single-tenant backends
+                Wb, nv, keys = Wb[0], jnp.int32(int(nv[0])), keys[0]
+                Wb = jnp.asarray(Wb)
+            elif self.plan.batch_w_sharding is not None:
+                # host -> shards in one copy (no staging hop via the default
+                # device)
+                Wb = jax.device_put(
+                    Wb, self.plan.batch_w_sharding(self.config, self.mesh)
+                )
+            else:
+                Wb = jnp.asarray(Wb)
+            nv = jnp.asarray(nv)
+        with TraceAnnotation("repro.engine.dispatch", step=self._step):
+            out = self._update(self._state, Wb, nv, keys)
         if self.plan.reports_overflow:
             # don't int() the overflow here: that would sync the host to the
             # device every batch and kill prefetch overlap. Drain every few
@@ -440,7 +448,8 @@ class TriangleCountEngine:
         if not self._pending_overflow:
             return
         pending, self._pending_overflow = self._pending_overflow, []
-        total = sum(int(o) for o in pending)
+        with TraceAnnotation("repro.engine.wait", step=self._step):
+            total = sum(int(o) for o in pending)
         if total > 0:
             self._escalate_capacity(total)
 
@@ -468,54 +477,55 @@ class TriangleCountEngine:
         Staging is separated from ingestion so callers (run_stream) can upload
         chunk k+1 while chunk k computes — double buffering the transfer.
         """
-        K, s, T = self.config.chunk_size, self.config.batch_size, self.n_tenants
-        if self._update_chunk is None:
-            raise ValueError(
-                "chunked ingest needs EngineConfig(chunk_size > 1) on a "
-                "banked plan ('single' or 'banked_pjit_*')"
-            )
-        arr = np.asarray(Ws, dtype=np.int32)
-        if arr.ndim == 3:
-            if arr.shape != (K, s, 2):
-                raise ValueError(f"chunk must be ({K}, {s}, 2), got {arr.shape}")
-            Wb_host = np.broadcast_to(arr[None], (T, K, s, 2))
-        elif arr.ndim == 4:
-            if arr.shape != (T, K, s, 2):
+        with TraceAnnotation("repro.engine.stage", step=self._step):
+            K, s, T = self.config.chunk_size, self.config.batch_size, self.n_tenants
+            if self._update_chunk is None:
                 raise ValueError(
-                    f"chunk must be ({T}, {K}, {s}, 2), got {arr.shape}"
+                    "chunked ingest needs EngineConfig(chunk_size > 1) on a "
+                    "banked plan ('single' or 'banked_pjit_*')"
                 )
-            Wb_host = arr
-        else:
-            raise ValueError(
-                f"chunk must be (K,s,2) or (T,K,s,2), got {arr.shape}"
+            arr = np.asarray(Ws, dtype=np.int32)
+            if arr.ndim == 3:
+                if arr.shape != (K, s, 2):
+                    raise ValueError(f"chunk must be ({K}, {s}, 2), got {arr.shape}")
+                Wb_host = np.broadcast_to(arr[None], (T, K, s, 2))
+            elif arr.ndim == 4:
+                if arr.shape != (T, K, s, 2):
+                    raise ValueError(
+                        f"chunk must be ({T}, {K}, {s}, 2), got {arr.shape}"
+                    )
+                Wb_host = arr
+            else:
+                raise ValueError(
+                    f"chunk must be (K,s,2) or (T,K,s,2), got {arr.shape}"
+                )
+            check_fault("engine.stage_chunk")  # chaos site: before the device put
+            if self.plan.chunk_w_sharding is not None:
+                # sharded plan: device_put straight through the plan's input
+                # sharding — one host->shards copy, no staging hop via the
+                # default device
+                Wb = jax.device_put(
+                    Wb_host, self.plan.chunk_w_sharding(self.config, self.mesh)
+                )
+            else:
+                Wb = jnp.asarray(Wb_host)
+            if n_valids is None:
+                nv_host = np.full((T, K), s, np.int64)
+            else:
+                nv_host = np.broadcast_to(
+                    np.asarray(n_valids, np.int64), (T, K)
+                )
+            # max over tenants per batch, summed over K — matches what K
+            # sequential ingest() calls would accumulate into diag.edges_ingested
+            edges = int(nv_host.max(axis=0).sum())
+            nv = jnp.asarray(nv_host, dtype=jnp.int32)
+            return StagedChunk(
+                Wb=Wb,
+                nv=nv,
+                edges=edges,
+                W_host=Wb_host if self._dynamic else None,
+                nv_host=np.asarray(nv_host, np.int64),
             )
-        check_fault("engine.stage_chunk")  # chaos site: before the device put
-        if self.plan.chunk_w_sharding is not None:
-            # sharded plan: device_put straight through the plan's input
-            # sharding — one host->shards copy, no staging hop via the
-            # default device
-            Wb = jax.device_put(
-                Wb_host, self.plan.chunk_w_sharding(self.config, self.mesh)
-            )
-        else:
-            Wb = jnp.asarray(Wb_host)
-        if n_valids is None:
-            nv_host = np.full((T, K), s, np.int64)
-        else:
-            nv_host = np.broadcast_to(
-                np.asarray(n_valids, np.int64), (T, K)
-            )
-        # max over tenants per batch, summed over K — matches what K
-        # sequential ingest() calls would accumulate into diag.edges_ingested
-        edges = int(nv_host.max(axis=0).sum())
-        nv = jnp.asarray(nv_host, dtype=jnp.int32)
-        return StagedChunk(
-            Wb=Wb,
-            nv=nv,
-            edges=edges,
-            W_host=Wb_host if self._dynamic else None,
-            nv_host=np.asarray(nv_host, np.int64),
-        )
 
     def ingest_chunk(self, Ws, n_valids=None) -> None:
         """Incorporate ``chunk_size`` batches in ONE device dispatch.
@@ -529,9 +539,10 @@ class TriangleCountEngine:
         check_fault("engine.ingest_chunk")  # chaos site: before any mutation
         c = Ws if isinstance(Ws, StagedChunk) else self.stage_chunk(Ws, n_valids)
         K = self.config.chunk_size
-        self._state = self._update_chunk(
-            self._state, c.Wb, c.nv, self._root_keys, self._step
-        )
+        with TraceAnnotation("repro.engine.dispatch", step=self._step):
+            self._state = self._update_chunk(
+                self._state, c.Wb, c.nv, self._root_keys, self._step
+            )
         self._step += K
         self._dyn_step += K
         # step-keyed cache: the pre-chunk answer stays addressable for
@@ -591,8 +602,9 @@ class TriangleCountEngine:
 
     def sync(self) -> None:
         """Block until all dispatched ingest work has completed on device."""
-        self._drain_overflow()
-        jax.block_until_ready(self._state)
+        with TraceAnnotation("repro.engine.wait", step=self._step):
+            self._drain_overflow()
+            jax.block_until_ready(self._state)
 
     # -- turnstile deletions / windowed expiry ------------------------------
     def _delete_program(self):
@@ -808,41 +820,45 @@ class TriangleCountEngine:
         failing the serve loop, counted in ``diag.query_fallbacks`` /
         ``diag.query_timeouts``.
         """
-        self._drain_overflow()
-        if not gather:
-            cached = self._est_cache.get(self._step)
-            if cached is not None:
-                self.diag.queries_answered += 1
-                self.diag.query_cache_hits += 1
-                return cached
-        out = None
-        if not gather and self._estimate_device is not None:
-            try:
-                out = self._query_device(timeout_s)
+        with TraceAnnotation("repro.engine.estimate", step=self._step):
+            self._drain_overflow()
+            if not gather:
+                cached = self._est_cache.get(self._step)
+                if cached is not None:
+                    self.diag.queries_answered += 1
+                    self.diag.query_cache_hits += 1
+                    return cached
+            out = None
+            if not gather and self._estimate_device is not None:
+                try:
+                    out = self._query_device(timeout_s)
+                    if not self.plan.banked:
+                        out = out[None]
+                except (FaultInjected, TimeoutError) as e:
+                    # graceful degradation: fall through to the gather oracle
+                    # below rather than killing the serving loop
+                    if isinstance(e, TimeoutError):
+                        self.diag.query_timeouts += 1
+                    self.diag.query_fallbacks += 1
+                    out = None
+            if out is None:
+                st = self._state
                 if not self.plan.banked:
-                    out = out[None]
-            except (FaultInjected, TimeoutError) as e:
-                # graceful degradation: fall through to the gather oracle
-                # below rather than killing the serving loop
-                if isinstance(e, TimeoutError):
-                    self.diag.query_timeouts += 1
-                self.diag.query_fallbacks += 1
-                out = None
-        if out is None:
-            st = self._state
-            if not self.plan.banked:
-                st = jax.tree.map(lambda x: x[None], st)
-            elif self.plan.bank_sharding is not None:
-                # the gather-to-host oracle: materialize the bank and answer
-                # on the default device — the same program as an unsharded
-                # engine, bit-identical across mesh shapes, O(T*r) bytes
-                # per query
-                st = jax.tree.map(np.asarray, st)
-            out = np.asarray(self._estimate(st))
-        self.diag.queries_answered += 1
-        if not gather:
-            self._est_cache = {self._step: out}
-        return out
+                    st = jax.tree.map(lambda x: x[None], st)
+                elif self.plan.bank_sharding is not None:
+                    # the gather-to-host oracle: materialize the bank and answer
+                    # on the default device — the same program as an unsharded
+                    # engine, bit-identical across mesh shapes, O(T*r) bytes
+                    # per query
+                    with TraceAnnotation("repro.engine.wait", step=self._step):
+                        st = jax.tree.map(np.asarray, st)
+                out = self._estimate(st)
+                with TraceAnnotation("repro.engine.wait", step=self._step):
+                    out = np.asarray(out)
+            self.diag.queries_answered += 1
+            if not gather:
+                self._est_cache = {self._step: out}
+            return out
 
     def _query_device(self, timeout_s: Optional[float]) -> np.ndarray:
         """Dispatch the device-resident query program, optionally bounded by
@@ -850,9 +866,13 @@ class TriangleCountEngine:
         thread past the deadline (XLA programs are not cancellable); the
         caller just stops waiting and serves the degraded answer."""
 
+        step = self._step
+
         def call() -> np.ndarray:
             check_fault("engine.estimate")  # chaos site: the device dispatch
-            return np.asarray(self._estimate_device(self._state))
+            out = self._estimate_device(self._state)
+            with TraceAnnotation("repro.engine.wait", step=step):
+                return np.asarray(out)
 
         if timeout_s is None:
             return call()
@@ -862,7 +882,8 @@ class TriangleCountEngine:
             )
         fut = self._query_pool.submit(call)
         try:
-            return fut.result(timeout=timeout_s)
+            with TraceAnnotation("repro.engine.wait", step=step):
+                return fut.result(timeout=timeout_s)
         except concurrent.futures.TimeoutError:
             raise TimeoutError(f"device query exceeded {timeout_s:.3f}s") from None
 
@@ -904,8 +925,9 @@ class TriangleCountEngine:
         st = self._state
         if not self.plan.banked:
             st = jax.tree.map(lambda x: x[None], st)
-        snap = {f: np.asarray(getattr(st, f)) for f in st._fields}
-        snap["root_keys"] = np.asarray(self._root_keys)
+        with TraceAnnotation("repro.engine.wait", step=self._step):
+            snap = {f: np.asarray(getattr(st, f)) for f in st._fields}
+            snap["root_keys"] = np.asarray(self._root_keys)
         snap["step"] = np.int64(self._step)
         snap["dyn_step"] = np.int64(self._dyn_step)
         snap["config"] = np.array(

@@ -72,6 +72,7 @@ from dataclasses import dataclass, field
 from typing import Any, Callable, Iterable, Optional
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.data.prefetch import PrefetchQueue, TenantQueues, superbatches
 from repro.engine.engine import SnapshotMismatch, TriangleCountEngine
@@ -268,7 +269,8 @@ def run_stream(
     def _admit(pos: int, W, nv) -> bool:
         if not res.validate:
             return True
-        reason = validate_batch(W, nv, max_vertex=res.max_vertex)
+        with TraceAnnotation("repro.stream.validate", step=engine.step):
+            reason = validate_batch(W, nv, max_vertex=res.max_vertex)
         if reason is None:
             return True
         # single-batch quarantine: a poisoned record must not kill the loop
@@ -277,11 +279,12 @@ def run_stream(
         return False
 
     def _emit_report() -> None:
-        astep, ests, age = _answer_query(engine, pf, res, rep, engine.step)
-        if wants_age:
-            on_report(astep, ests, engine.edges_seen(), stale_age=age)
-        else:
-            on_report(astep, ests, engine.edges_seen())
+        with TraceAnnotation("repro.stream.report", step=engine.step):
+            astep, ests, age = _answer_query(engine, pf, res, rep, engine.step)
+            if wants_age:
+                on_report(astep, ests, engine.edges_seen(), stale_age=age)
+            else:
+                on_report(astep, ests, engine.edges_seen())
         rep.queries += 1
 
     def after_ingest(n_batches: int, n_edges: int) -> None:
@@ -295,19 +298,25 @@ def run_stream(
             # step (estimate_tenant etc.) hit the engine's per-step cache
             _emit_report()
         if ckpt and ckpt_every and rep.batches % ckpt_every == 0:
-            ckpt.save(
-                engine.step,
-                engine.snapshot(),
-                {"config_hash": config_hash(meta), **meta,
-                 "source_pos": committed[0]},
-            )
+            with TraceAnnotation("repro.stream.checkpoint", step=engine.step):
+                ckpt.save(
+                    engine.step,
+                    engine.snapshot(),
+                    {"config_hash": config_hash(meta), **meta,
+                     "source_pos": committed[0]},
+                )
 
     def drained():
-        """Post-skip (position, batch) pairs out of the prefetch queue."""
+        """Post-skip (position, batch) pairs out of the prefetch queue; the
+        fetches of the skipped prefix carry ``skipped=1`` in their span."""
         seen = 0
         while True:
             try:
-                batch, stale = pf.get()
+                with TraceAnnotation(
+                    "repro.stream.fetch", step=engine.step,
+                    skipped=int(seen < skip),
+                ):
+                    batch, stale = pf.get()
             except StopIteration:
                 return
             rep.stale_batches += int(stale)
@@ -366,14 +375,15 @@ def run_stream(
     rep.retries += pf.retries
     rep.query_fallbacks = engine.diag.query_fallbacks - fallbacks0
     if ckpt:
-        ckpt.wait()
-        ckpt.save(
-            engine.step,
-            engine.snapshot(),
-            {"config_hash": config_hash(meta), **meta,
-             "source_pos": committed[0]},
-        )
-        ckpt.wait()
+        with TraceAnnotation("repro.stream.checkpoint", step=engine.step):
+            ckpt.wait()
+            ckpt.save(
+                engine.step,
+                engine.snapshot(),
+                {"config_hash": config_hash(meta), **meta,
+                 "source_pos": committed[0]},
+            )
+            ckpt.wait()
     return rep
 
 

@@ -12,6 +12,7 @@ from __future__ import annotations
 
 import os
 import pathlib
+import re
 
 import jax
 
@@ -28,7 +29,20 @@ def use_compile_cache() -> None:
     nothing is set here. Otherwise the cache goes to ``COMPILE_CACHE_DIR``.
     A cache hit skips the backend compile, so it also skips the
     ``backend_compile`` event that ``repro.engine.XlaCompileCounter``
-    counts."""
+    counts.
+
+    The cache is keyed on the programs' op metadata too: the named scopes
+    (``jax.named_scope``) live there, and the profiler reports an op under
+    the scope path of the executable that ran. Keyed without it, a program
+    that differs from a cached one only in its scopes would run, and be
+    profiled, under the cached program's names. Source paths in the
+    metadata are taken relative to the checkout, so a copy of the same
+    code elsewhere finds the same entries."""
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+    jax.config.update(
+        "jax_hlo_source_file_canonicalization_regex",
+        "^" + re.escape(str(COMPILE_CACHE_DIR.parent)) + "/",
+    )
     if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
         return
     jax.config.update("jax_compilation_cache_dir", str(COMPILE_CACHE_DIR))

@@ -80,17 +80,18 @@ def multisearch_bounds(sorted_keys: Array, queries: Array) -> tuple[Array, Array
     compare-reduce in VMEM, zero gathers, both bounds from the same streaming
     pass over the keys.
     """
-    if multisearch_backend() == "pallas":
-        from repro.kernels.ops import multisearch_counts_op
+    with jax.named_scope("multisearch"):
+        if multisearch_backend() == "pallas":
+            from repro.kernels.ops import multisearch_counts_op
 
-        return multisearch_counts_op(sorted_keys, queries)
-    lt = jnp.searchsorted(
-        sorted_keys, queries, side="left", method=_XLA_SEARCH_METHOD
-    ).astype(jnp.int32)
-    le = jnp.searchsorted(
-        sorted_keys, queries, side="right", method=_XLA_SEARCH_METHOD
-    ).astype(jnp.int32)
-    return lt, le
+            return multisearch_counts_op(sorted_keys, queries)
+        lt = jnp.searchsorted(
+            sorted_keys, queries, side="left", method=_XLA_SEARCH_METHOD
+        ).astype(jnp.int32)
+        le = jnp.searchsorted(
+            sorted_keys, queries, side="right", method=_XLA_SEARCH_METHOD
+        ).astype(jnp.int32)
+        return lt, le
 
 
 def multisearch_lt(sorted_keys: Array, queries: Array) -> Array:
@@ -103,13 +104,14 @@ def multisearch_lt(sorted_keys: Array, queries: Array) -> Array:
     ``multisearch_bounds``; on "pallas" the counting kernel computes both
     bounds in its single streaming pass anyway, so this simply drops ``le``.
     """
-    if multisearch_backend() == "pallas":
-        from repro.kernels.ops import multisearch_counts_op
+    with jax.named_scope("multisearch"):
+        if multisearch_backend() == "pallas":
+            from repro.kernels.ops import multisearch_counts_op
 
-        return multisearch_counts_op(sorted_keys, queries)[0]
-    return jnp.searchsorted(
-        sorted_keys, queries, side="left", method=_XLA_SEARCH_METHOD
-    ).astype(jnp.int32)
+            return multisearch_counts_op(sorted_keys, queries)[0]
+        return jnp.searchsorted(
+            sorted_keys, queries, side="left", method=_XLA_SEARCH_METHOD
+        ).astype(jnp.int32)
 
 
 def exact_multisearch(
