@@ -14,7 +14,9 @@ structure built once; O(s log s)) lifts from cache lines to ICI links:
 
 * ``make_pjit_update(mesh, w_mode)`` — one jit program over the whole mesh.
     - w_mode="independent":     W replicated; each device sorts the full batch
-      for its estimator shard. Zero collectives, p-times duplicated sort FLOPs.
+      for its estimator shard, p-times duplicated sort FLOPs. The multisearch
+      sorts queries and keys together, so XLA all-gathers each search's
+      queries and every device runs the whole merge (PERF.md §7).
     - w_mode="coordinated_xla": W sharded; XLA's SPMD partitioner inserts the
       collectives for the global sort/searches automatically.
 
@@ -244,8 +246,11 @@ def make_banked_pjit_update(
     — and every device in a tenant group needs the full batch structure for
     its estimator shard's multisearches anyway.
     The estimator-dim work (reservoir draws, Q1/Q2/Q3 query vectors) stays
-    sharded in both modes. ``make_banked_pjit_chunk_update`` is the K-batch
-    fused variant (``scheme.chunk_update`` under the same shardings).
+    sharded in both modes; the searches do not: the merge sorts each query
+    vector with its keys, so the partitioner all-gathers the queries within
+    the tenant group and every device of the group merges all of them.
+    ``make_banked_pjit_chunk_update`` is the K-batch fused variant
+    (``scheme.chunk_update`` under the same shardings).
     """
     scheme = resolve_scheme(scheme)
     state_sh = banked_state_sharding(mesh, tenant_axis, scheme)

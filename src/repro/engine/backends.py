@@ -4,13 +4,17 @@ One engine API, six execution plans over the same ``bulk_update_all``
 semantics (and therefore the same estimate distribution — counter-based RNG
 makes the paths interchangeable mid-stream):
 
-  single                   jit(vmap(bulk_update_all)) over the tenant axis.
+  single                   jit(vmap(bulk_update_all)) over the tenant axis
+                           (a bank of one runs the update on its row).
                            The default on one device; N streams share one
                            program, all state on one device.
   pjit_independent         paper Section 5's "independent bulk parallel": W
                            replicated, each device sorts the whole batch for
-                           its estimator shard. Zero collectives, p-times
-                           duplicated sort work. Single-tenant.
+                           its estimator shard; p-times duplicated sort work.
+                           The multisearch merges queries and keys in one
+                           sort, so the partitioner all-gathers each search's
+                           queries and every device merges all of them
+                           (PERF.md §7). Single-tenant.
   pjit_coordinated         W sharded; XLA's SPMD partitioner inserts the
                            collectives for the global sorts/searches.
                            Single-tenant.
@@ -27,6 +31,8 @@ makes the paths interchangeable mid-stream):
   banked_pjit_coordinated  same layout with W sharded across the estimator
                            axes — SPMD collectives stay *inside* each tenant
                            group; the tenant axis itself is collective-free.
+                           In both banked plans each search's queries are
+                           all-gathered within the tenant group, as above.
 
 Chunked ingest (``build_chunk``, on the single + banked plans) routes through
 ``scheme.chunk_update`` -> ``repro.core.bulk.bulk_update_chunk``, which
@@ -63,6 +69,7 @@ under "auto" and are rejected when named explicitly.
 """
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 from typing import Any, Callable, Optional
 
@@ -133,9 +140,34 @@ def config_scheme(config) -> EstimatorScheme:
     )
 
 
+def _over_tenants(fn: Callable, in_axes=0) -> Callable:
+    """``jax.vmap(fn, in_axes)`` over the bank's leading tenant axis; a bank
+    of one tenant runs ``fn`` on its row and gets the axis back after.
+
+    On a TPU a sort over a (1, n) array tiles 1 x 128 where one over (n,)
+    tiles by 1024: the multisearch's merge sorts ran 7-8x slower vmapped over
+    one tenant (PERF.md §6). The choice is made while tracing, from the
+    bank's shape; the name stays ``fn``'s, which names the compiled module.
+    """
+    batched = jax.vmap(fn, in_axes=in_axes)
+
+    @functools.wraps(fn)
+    def run(state, *args):
+        if jax.tree.leaves(state)[0].shape[0] != 1:
+            return batched(state, *args)
+        axes = in_axes if isinstance(in_axes, tuple) else (in_axes,) * (len(args) + 1)
+        row = [
+            a if ax is None else jax.tree.map(lambda x: x[0], a)
+            for a, ax in zip((state, *args), axes)
+        ]
+        return jax.tree.map(lambda x: x[None], fn(*row))
+
+    return run
+
+
 def _build_single(config, mesh) -> Callable:
     scheme = config_scheme(config)
-    return jax.jit(jax.vmap(scheme.bulk_update), donate_argnums=(0,))
+    return jax.jit(_over_tenants(scheme.bulk_update), donate_argnums=(0,))
 
 
 def _build_single_chunk(config, mesh) -> Callable:
@@ -143,7 +175,7 @@ def _build_single_chunk(config, mesh) -> Callable:
     # ride in unvmapped/traced so one compiled program serves the whole stream
     scheme = config_scheme(config)
     return jax.jit(
-        jax.vmap(scheme.chunk_update, in_axes=(0, 0, 0, 0, None)),
+        _over_tenants(scheme.chunk_update, in_axes=(0, 0, 0, 0, None)),
         donate_argnums=(0,),
     )
 
@@ -153,7 +185,7 @@ def _build_single_chunk_elastic(config, mesh) -> Callable:
     # that joined at different stream positions stay on their own RNG streams
     scheme = config_scheme(config)
     return jax.jit(
-        jax.vmap(scheme.chunk_update, in_axes=(0, 0, 0, 0, 0)),
+        _over_tenants(scheme.chunk_update, in_axes=(0, 0, 0, 0, 0)),
         donate_argnums=(0,),
     )
 
@@ -200,7 +232,7 @@ def _build_banked_pjit_chunk(w_mode: str, per_tenant_step0: bool = False):
 
 def _build_single_delete(config, mesh) -> Callable:
     scheme = config_scheme(config)
-    return jax.jit(jax.vmap(scheme.delete_update), donate_argnums=(0,))
+    return jax.jit(_over_tenants(scheme.delete_update), donate_argnums=(0,))
 
 
 def _build_pjit_delete(config, mesh) -> Callable:

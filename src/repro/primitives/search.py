@@ -1,19 +1,23 @@
 """Multisearch primitives (paper Lemma 3.5).
 
-The paper's cache-oblivious merge-based multisearch answers m lookups against a
-sorted sequence of n key-value pairs in O(sort(n)+sort(m)) misses. With both
-sides presorted it degrades to O(scan(n+m)). We express each lookup set as a
-vectorized binary search (``jnp.searchsorted``) over presorted int64 keys;
-the Pallas kernel in repro.kernels.multisearch is a gather-free counting
-variant, selectable by name.
+The paper's cache-oblivious merge-based multisearch answers q lookups against
+a sorted sequence of n keys in O(sort(n + q)) work: merge the queries into
+the keys and read each query's insertion point off the merged order.
 
-``multisearch_bounds`` is the hot-path entry point: one call answers both
-insertion points (left/right) for a whole fused query vector. "auto" resolves
-to ``jnp.searchsorted`` on every platform: the counting kernel compares every
-query with every key (O(q*n) work), so whether it should ever be the default
-is for a chip benchmark to decide. Callers that fuse their lookups into one
-query vector per sorted structure pay one multisearch per structure instead
-of one per query role.
+``multisearch_bounds`` (both insertion points) and ``multisearch_lt`` (the
+left one) are the hot-path entry points: one call answers a whole fused
+query vector. "auto" resolves to "xla" on every platform, and "xla" is the
+merge (``_merge_bounds``): one sort of keys and queries together, a running
+count of keys, and one un-permute back to query order. On a TPU v5e it
+answered every measured shape at least as fast as the bisection of
+``jnp.searchsorted`` (one dependent gather per query and step), from 2^10
+queries into 2^15 keys (0.70 against 0.89 ms) to 2^23 queries into 2^21
+keys (85 ms against 8.7 s), and tied at 2^14 queries into 2^21 keys
+(``PERF.md`` §6). The Pallas kernel in ``repro.kernels.multisearch`` is a
+gather-free counting variant that compares every query with every key,
+selectable by name. Callers that fuse their lookups into one query vector
+per sorted structure pay one multisearch per structure instead of one per
+query role.
 """
 from __future__ import annotations
 
@@ -39,7 +43,7 @@ if _backend not in MULTISEARCH_BACKENDS:
 
 def set_multisearch_backend(name: str) -> None:
     """Force the multisearch backend: "auto" (= "xla" on every platform),
-    "xla" (jnp.searchsorted), or "pallas" (counting kernel; compiled on TPU,
+    "xla" (the merge), or "pallas" (counting kernel; compiled on TPU,
     interpret mode elsewhere — slow, for parity testing only). The choice is resolved at trace
     time, so switching also clears the jit caches — otherwise already-compiled
     programs would silently keep their old backend forever."""
@@ -59,39 +63,73 @@ def multisearch_backend() -> str:
     return "xla" if _backend == "auto" else _backend
 
 
-# XLA binary-search flavor. Every method computes identical insertion
-# points, so this is purely a performance knob. "scan" (the jnp default) is
-# deliberately pinned: "scan_unrolled" looks ~1.6x faster in a standalone
-# searchsorted microbenchmark on CPU, but embedded in the full chunk-ingest
-# program it is ~3.7x SLOWER end-to-end (measured on the r=65536, s=4096,
-# K=8 cell: 225ms -> 742ms per chunk) — the unrolled bisection bloats the
-# program and defeats fusion around it. Benchmark any change to this knob
-# with benchmarks/fused.py, not with an isolated searchsorted loop.
-_XLA_SEARCH_METHOD = "scan"
+def _running(x: Array, scan, op, unit: int, reverse: bool = False) -> Array:
+    """The running ``op`` of a 1-D ``x`` (``scan`` is ``lax.cumsum`` or
+    ``lax.cummin``, ``unit`` the identity of ``op``), scanned along rows of
+    128 entries and carried across the rows.
+
+    The TPU compiler turns a 1-D scan into such rows itself, with ops that
+    lose the named scope; written out, the rows keep it, and only the carry
+    across rows (1/128 of the entries) is a 1-D scan of its own.
+    """
+    m = x.shape[0]
+    rows = jnp.pad(x, (0, -m % 128), constant_values=unit).reshape(-1, 128)
+    within = scan(rows, axis=1, reverse=reverse)
+    unit_row = jnp.full((1,), unit, x.dtype)
+    if reverse:
+        carry = jnp.concatenate([scan(within[:, 0], reverse=True)[1:], unit_row])
+    else:
+        carry = jnp.concatenate([unit_row, scan(within[:, -1])[:-1]])
+    return op(within, carry[:, None]).reshape(-1)[:m]
+
+
+def _merge_bounds(sorted_keys: Array, queries: Array, le: bool):
+    """The insertion points from one merged order (paper Lemma 3.5): sort
+    the queries and the keys together, queries ahead of equal keys, and
+    count the keys before each query's slot.
+
+    Each entry carries its index in ``concat(queries, keys)`` as the last
+    sort key, so a query (index < q) sorts ahead of the keys equal to it and
+    the order is total. The int64 values sort as two int32 keys, the high
+    word and the low word less 2^31, which order exactly as the int64 does.
+    ``lt`` at a query's slot is the inclusive running count of keys there;
+    ``le`` is that count at the end of the slot's run of equal values, a
+    reverse running minimum over the run ends. One sort by the carried
+    index puts both back in query order.
+    """
+    n, q = sorted_keys.shape[0], queries.shape[0]
+    vals = jnp.concatenate([queries, sorted_keys])
+    hi = (vals >> 32).astype(jnp.int32)
+    lo = ((vals & 0xFFFFFFFF) - (1 << 31)).astype(jnp.int32)
+    idx = jnp.arange(n + q, dtype=jnp.int32)
+    hi, lo, idx = jax.lax.sort((hi, lo, idx), num_keys=3, is_stable=False)
+    lt = _running((idx >= q).astype(jnp.int32), jax.lax.cumsum, jnp.add, 0)
+    out = [lt]
+    if le:
+        last = jnp.ones((min(n + q, 1),), bool)
+        run_end = jnp.concatenate([(hi[:-1] != hi[1:]) | (lo[:-1] != lo[1:]), last])
+        ends = jnp.where(run_end, lt, n)
+        out.append(_running(ends, jax.lax.cummin, jnp.minimum, n, reverse=True))
+    out = jax.lax.sort((idx, *out), num_keys=1, is_stable=False)[1:]
+    return tuple(o[:q] for o in out) if le else out[0][:q]
 
 
 def multisearch_bounds(sorted_keys: Array, queries: Array) -> tuple[Array, Array]:
     """(count_lt, count_le) per query: the searchsorted left/right insertion
     points into ``sorted_keys``, int32, answered in one fused multisearch.
 
-    This is the backend-dispatched hot-path primitive: by default two
-    ``jnp.searchsorted`` binary searches; with the backend forced to "pallas"
-    the chunked counting kernel from ``repro.kernels.multisearch`` — dense
-    compare-reduce in VMEM, zero gathers, both bounds from the same streaming
-    pass over the keys.
+    This is the backend-dispatched hot-path primitive: on "xla" the merge;
+    with the backend forced to "pallas" the chunked counting kernel from
+    ``repro.kernels.multisearch`` — dense compare-reduce in VMEM, zero
+    gathers, both bounds from the same streaming pass over the keys.
     """
     with jax.named_scope("multisearch"):
         if multisearch_backend() == "pallas":
             from repro.kernels.ops import multisearch_counts_op
 
             return multisearch_counts_op(sorted_keys, queries)
-        lt = jnp.searchsorted(
-            sorted_keys, queries, side="left", method=_XLA_SEARCH_METHOD
-        ).astype(jnp.int32)
-        le = jnp.searchsorted(
-            sorted_keys, queries, side="right", method=_XLA_SEARCH_METHOD
-        ).astype(jnp.int32)
-        return lt, le
+        with jax.named_scope("merge"):
+            return _merge_bounds(sorted_keys, queries, le=True)
 
 
 def multisearch_lt(sorted_keys: Array, queries: Array) -> Array:
@@ -109,9 +147,8 @@ def multisearch_lt(sorted_keys: Array, queries: Array) -> Array:
             from repro.kernels.ops import multisearch_counts_op
 
             return multisearch_counts_op(sorted_keys, queries)[0]
-        return jnp.searchsorted(
-            sorted_keys, queries, side="left", method=_XLA_SEARCH_METHOD
-        ).astype(jnp.int32)
+        with jax.named_scope("merge"):
+            return _merge_bounds(sorted_keys, queries, le=False)
 
 
 def exact_multisearch(

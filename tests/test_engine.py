@@ -85,6 +85,44 @@ class TestMultiTenant:
             estimate(jax.tree.map(jnp.asarray, ref), groups=9)
         )
 
+    @pytest.mark.parametrize("program", ["build", "build_chunk", "build_delete"])
+    def test_single_plan_bank_of_one_matches_its_row_in_a_bank(self, program):
+        """The ``single`` plan runs a bank of one tenant on its row and a
+        larger bank vmapped: tenant 0 comes out the same either way, and the
+        compiled module keeps the update's name."""
+        from repro.core.schemes import GLOBAL
+
+        cfg = EngineConfig(r=R, batch_size=BS)
+        fn = getattr(select_backend(cfg, None), program)(cfg, None)
+        module = {"build": "bulk_update", "build_chunk": "chunk_update",
+                  "build_delete": "delete_update"}[program]
+        rng = np.random.default_rng(2)
+        W = rng.integers(0, 20, size=(2, 3, BS, 2)).astype(np.int32)
+        nv = np.array([[BS, BS, 9], [BS, 5, BS]], np.int32)
+        keys = np.stack([np.asarray(jax.random.PRNGKey(s)) for s in (4, 6)])
+        if program == "build_chunk":
+            args = lambda T: (W[:T], nv[:T], keys[:T], 0)  # noqa: E731
+        elif program == "build":
+            args = lambda T: (W[:T, 0], nv[:T, 0], keys[:T])  # noqa: E731
+        else:
+            args = lambda T: (W[:T, 0], nv[:T, 0])  # noqa: E731
+        out = {}
+        for T in (1, 2):
+            bank = jax.tree.map(
+                lambda x: jnp.stack([x] * T), GLOBAL.init_state(R)
+            )
+            if program == "build_delete":  # something to delete from
+                bank = select_backend(cfg, None).build(cfg, None)(
+                    bank, W[:T, 1], nv[:T, 1], keys[:T]
+                )
+            text = fn.lower(bank, *args(T)).as_text()
+            assert text.startswith(f"module @jit_{module} "), text[:80]
+            out[T] = jax.tree.map(np.asarray, fn(bank, *args(T)))
+        for f in out[1]._fields:
+            np.testing.assert_array_equal(
+                getattr(out[1], f)[0], getattr(out[2], f)[0], err_msg=f
+            )
+
 
 class TestSnapshotRestore:
     def test_midstream_roundtrip_bitforbit(self):

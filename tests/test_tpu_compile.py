@@ -13,6 +13,8 @@ this file. Kernels are compiled through ``repro.kernels.ops`` with
 (``interpret=False``). A kernel the compiler refuses is a strict xfail
 carrying the compiler's first line; none of those is what ``auto`` selects.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -164,4 +166,31 @@ def test_ingest_program_compiles_at_paper_shape(chip, name):
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes)
     assert used < 4 * 2**30, used  # far inside a v5e's 16 GB
-    assert "tpu_custom_call" not in compiled.as_text()  # the XLA path
+    text = compiled.as_text()
+    assert "tpu_custom_call" not in text  # the XLA path
+    # a bank of one runs on its row: no sort is over a (1, n) batch, which
+    # the TPU tiles 1 x 128 (the chunk program's structure build sorts its
+    # K batches as one (K, n) batch)
+    sorts = re.findall(r"= \(?[su]\d+\[([\d,]*)\][^=]* sort\(", text)
+    assert sorts and not any(d.startswith("1,") for d in sorts), sorts
+
+
+def test_merge_scans_keep_their_scope(chip):
+    """The merge's running count and minimum run along 128-entry rows, which
+    the TPU compiler leaves under the ``multisearch/merge`` scope where
+    ``multisearch_share`` reads them; only the carry across the rows (1/128
+    of the entries) is rewritten without it."""
+    from repro.primitives.search import multisearch_bounds
+
+    n, q = 2**12, 2**14
+    args = _shape(chip, (n,), jnp.int64), _shape(chip, (q,), jnp.int64)
+    text = jax.jit(multisearch_bounds).lower(*args).compile().as_text()
+    entry = text[text.index("\nENTRY"):]
+    rows = [
+        line for line in entry.splitlines()
+        if " reduce-window(" in line
+        and int(re.search(r"\[(\d+),128\]", line + "[0,128]").group(1)) * 128 >= n + q
+    ]
+    assert len(rows) == 2, rows  # the running count and the running minimum
+    for line in rows:
+        assert "/multisearch/merge/" in line, line
